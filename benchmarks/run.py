@@ -13,21 +13,22 @@ and step-microbenchmarks. Prints ``name,us_per_call,derived`` CSV rows.
   trainer — scan-native trainer (train_batched: real reduced transformer
           inside the engine jit) vs the legacy per-strategy ElasticTrainer
           Python loop on an 8-strategy × 8-seed grid.
-  sharded — engine ticks/sec under `simulate_sharded` at 1/2/4/8 forced
-          host devices (subprocess per count; cell-ticks/sec + speedup
-          vs 1 device).
-  serve — rolling-horizon bidding service (service.server) at 1/2/4
-          forced host devices: replan latency p50/p95, decisions/sec,
-          and per-job regret vs hindsight / best static paper plan.
+  sharded — engine ticks/sec under `simulate_sharded` over the first
+          1/2/4/8 visible devices (cell-ticks/sec + speedup vs 1 device).
+  serve — rolling-horizon bidding service (service.server) over the
+          first 1/2/4 visible devices: replan latency p50/p95,
+          decisions/sec, and per-job regret vs hindsight / best static
+          paper plan.
   multibid — K=1..5 bid levels (core.multibid.optimize_multibid) on the
           engine: expected vs simulated cost curve (beyond-paper §VII).
   zoo  — the model zoo under preemption (trainer.train_zoo): tokens/sec
           for a small real reduced-qwen2 config under elastic masking,
-          cost-vs-loss frontier across fixed-bid levels, the bf16
-          mixed-precision carry, and persistent-jit-cache warm start.
+          cost-vs-loss frontier across fixed-bid levels, and the bf16
+          mixed-precision carry.
   chaos — recovery overhead of the self-healing supervisor: the same
           durable run unfailed vs under a seeded kill+corrupt fault plan
           (restarts, ticks lost, MTTR, wall overhead %).
+          Its workers need the device, so it runs alone (--only chaos).
   roofline — per (arch × shape) dominant roofline term from the dry-run
           JSON (results/dryrun_singlepod.json), if present.
   steps — wall-time microbenchmarks of the elastic train/serve steps on
@@ -723,87 +724,50 @@ def bench_kernels():
 # sharded engine scaling across virtual devices
 # --------------------------------------------------------------------------
 
-_SHARDED_BENCH_SCRIPT = r"""
-import os, sys
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                           + sys.argv[1])
-import json, time
-import numpy as np
-import jax
-from repro.data.synthetic import QuadraticProblem
-from repro.launch.mesh import make_scenario_mesh
-from repro.sim import engine
+def _device_counts(wanted):
+    """The device counts of ``wanted`` this process can build a mesh
+    over."""
+    import jax
 
-n_dev = int(sys.argv[1])
-S, R, n_ticks = (int(x) for x in sys.argv[2:5])
-if jax.device_count() < n_dev:
-    print("RESULT " + json.dumps({"skip": jax.device_count()}))
-    raise SystemExit(0)
-quad = QuadraticProblem(dim=16, n_samples=256, cond=5.0, noise=0.2, seed=0)
-w0 = np.asarray(quad.w_star + 1.0, np.float32)
-scenarios = [engine.Scenario(
-    price=engine.PriceSpec.uniform(0.2, 1.0), alpha=0.4 / quad.L,
-    bid_schedule=np.tile([b, b, b, b], (max(2, n_ticks // 2), 1)),
-    rt_kind="exp", rt_lam=2.0, idle_step=0.5, name=f"s{i}")
-    for i, b in enumerate(np.linspace(0.4, 1.0, S))]
-batch = engine.stack_scenarios(scenarios)
-program = engine.quadratic_program("minibatch", 8)
-data = engine.jax_quadratic(quad)
-cfg = engine.SimConfig(n_ticks=n_ticks, batch=8)
-mesh = make_scenario_mesh(n_dev)
-
-def run():
-    res = engine.simulate_sharded(batch, program, w0, data, R, cfg,
-                                  mesh=mesh)
-    jax.block_until_ready(res.final_model)
-    return res
-
-run()                                   # compile
-t0 = time.perf_counter()
-run()
-us = (time.perf_counter() - t0) * 1e6
-print("RESULT " + json.dumps({"us": us}))
-"""
+    return [n for n in wanted if n <= jax.device_count()]
 
 
 def bench_sharded():
-    """Engine throughput under `simulate_sharded` at 1/2/4/8 forced host
-    devices (one subprocess per device count, so XLA_FLAGS takes effect
-    before backend init — the virtual-device CPU recipe from README's
-    "Running on a mesh"). Derived column reports cell-ticks/sec
-    (S × R × n_ticks / wall) and the speedup over the 1-device run.
+    """Engine throughput under `simulate_sharded` over meshes of the
+    first 1/2/4/8 visible devices, in this process. Derived column reports
+    cell-ticks/sec (S × R × n_ticks / wall) and the speedup over the
+    1-device mesh. On a CPU host, force devices with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before the
+    benchmark starts; counts beyond the visible devices are not run."""
+    import jax
 
-    On the 1-core CI box the virtual devices share one core, so the
-    honest expectation is ~flat scaling there; the row exists to keep the
-    sharded path exercised and to report real scaling on multi-core
-    hosts."""
-    import subprocess
-    import sys
+    from repro.data.synthetic import QuadraticProblem
+    from repro.launch.mesh import make_scenario_mesh
+    from repro.sim import engine
 
     S, R, n_ticks = (8, 2, 8) if SMOKE else (64, 8, 200)
-    counts = [1, 2] if SMOKE else [1, 2, 4, 8]
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
-                               if "PYTHONPATH" in env else "")
-    env.pop("XLA_FLAGS", None)
+    quad = QuadraticProblem(dim=16, n_samples=256, cond=5.0, noise=0.2,
+                            seed=0)
+    w0 = np.asarray(quad.w_star + 1.0, np.float32)
+    scenarios = [engine.Scenario(
+        price=engine.PriceSpec.uniform(0.2, 1.0), alpha=0.4 / quad.L,
+        bid_schedule=np.tile([b, b, b, b], (max(2, n_ticks // 2), 1)),
+        rt_kind="exp", rt_lam=2.0, idle_step=0.5, name=f"s{i}")
+        for i, b in enumerate(np.linspace(0.4, 1.0, S))]
+    batch = engine.stack_scenarios(scenarios)
+    program = engine.quadratic_program("minibatch", 8)
+    data = engine.jax_quadratic(quad)
+    cfg = engine.SimConfig(n_ticks=n_ticks, batch=8)
     base_us = None
-    for n_dev in counts:
-        out = subprocess.run(
-            [sys.executable, "-c", _SHARDED_BENCH_SCRIPT, str(n_dev),
-             str(S), str(R), str(n_ticks)],
-            env=env, capture_output=True, text=True, timeout=900)
-        if out.returncode != 0:
-            raise RuntimeError(f"sharded bench subprocess (d={n_dev}) "
-                               f"failed:\n{out.stderr[-2000:]}")
-        line = [l for l in out.stdout.splitlines()
-                if l.startswith("RESULT ")][-1]
-        rec = json.loads(line[len("RESULT "):])
-        if "skip" in rec:
-            emit(f"sharded_d{n_dev}", 0.0,
-                 f"skipped;only_{rec['skip']}_devices")
-            continue
-        us = rec["us"]
+    for n_dev in _device_counts([1, 2] if SMOKE else [1, 2, 4, 8]):
+        mesh = make_scenario_mesh(n_dev)
+
+        def run():
+            res = engine.simulate_sharded(batch, program, w0, data, R, cfg,
+                                          mesh=mesh)
+            jax.block_until_ready(res.final_model)
+
+        _, us = _timed(run)
         if base_us is None:
             base_us = us
         ticks_per_sec = S * R * n_ticks / (us / 1e6)
@@ -813,93 +777,46 @@ def bench_sharded():
              f"speedup_vs_d1={base_us / us:.2f}x")
 
 
-_SERVE_BENCH_SCRIPT = r"""
-import os, sys
-n_dev = int(sys.argv[1])
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                           + sys.argv[1])
-import json, time
-import jax
-if jax.device_count() < n_dev:
-    print("RESULT " + json.dumps({"skip": jax.device_count()}))
-    raise SystemExit(0)
-
-from repro.core.cost_model import RuntimeModel
-from repro.launch.mesh import make_scenario_mesh
-from repro.service import BidServer, JobSpec, ServeConfig, synthetic_feed
-from repro.service.server import demo_problem
-
-ticks, horizon, warmup, score_ticks = (int(x) for x in sys.argv[2:6])
-quad, w0, prob = demo_problem(seed=0)
-feed = synthetic_feed(n_markets=2, n_ticks=ticks, seed=3)
-jobs = [JobSpec(name=f"job{i}", market=i % 2, eps=0.5, theta=60.0,
-                n_workers=4) for i in range(2)]
-cfg = ServeConfig(horizon=horizon, warmup=warmup, score_seeds=2, seed=0,
-                  batch=4, idle_step=0.25, multibid_partitions=((2, 2),),
-                  score_ticks=score_ticks or None)
-mesh = make_scenario_mesh(n_dev) if n_dev > 1 else None
-t0 = time.perf_counter()
-rep = BidServer(feed, jobs, prob=prob, quad=quad, w0=w0, alpha=prob.alpha,
-                rt_true=RuntimeModel(kind="exp", lam=2.0, delta=0.05),
-                cfg=cfg, mesh=mesh).run()
-wall = time.perf_counter() - t0
-s = rep["summary"]
-out = {"wall_s": wall, "replan_p50_ms": s["replan_p50_ms"],
-       "replan_p95_ms": s["replan_p95_ms"],
-       "decisions_per_sec": s["decisions_per_sec"],
-       "decisions": s["decisions"],
-       "completed": sum(j["completed"] for j in s["jobs"].values()),
-       "jobs": {name: {k: j[k] for k in
-                       ("cost", "regret_vs_hindsight",
-                        "regret_vs_static_paper")}
-                for name, j in s["jobs"].items()}}
-print("RESULT " + json.dumps(out))
-"""
-
-
 def bench_serve():
-    """Rolling-horizon bidding service throughput at 1/2/4 forced host
-    devices (subprocess per count; d1 scores candidates vmapped, d>1
+    """Rolling-horizon bidding service throughput over the first 1/2/4
+    visible devices, in this process (d1 scores candidates vmapped, d>1
     shards scoring over a `make_scenario_mesh` — bit-exact either way,
     see tests/test_serve.py). Derived columns report replan latency
     p50/p95, decisions/sec, and — from the 1-device run — each job's
     regret vs the hindsight-optimal static bid and vs the best static
-    paper plan. The 1-core CI box shares one core across the virtual
-    devices, so ~flat scaling is the honest expectation there."""
-    import subprocess
-    import sys
+    paper plan."""
+    from repro.core.cost_model import RuntimeModel
+    from repro.launch.mesh import make_scenario_mesh
+    from repro.service import BidServer, JobSpec, ServeConfig, synthetic_feed
+    from repro.service.server import demo_problem
 
     ticks, horizon, warmup, score_ticks = \
         (24, 8, 8, 16) if SMOKE else (120, 24, 24, 0)
-    counts = [1] if SMOKE else [1, 2, 4]
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
-                               if "PYTHONPATH" in env else "")
-    env.pop("XLA_FLAGS", None)
-    for n_dev in counts:
-        out = subprocess.run(
-            [sys.executable, "-c", _SERVE_BENCH_SCRIPT, str(n_dev),
-             str(ticks), str(horizon), str(warmup), str(score_ticks)],
-            env=env, capture_output=True, text=True, timeout=900)
-        if out.returncode != 0:
-            raise RuntimeError(f"serve bench subprocess (d={n_dev}) "
-                               f"failed:\n{out.stderr[-2000:]}")
-        line = [l for l in out.stdout.splitlines()
-                if l.startswith("RESULT ")][-1]
-        rec = json.loads(line[len("RESULT "):])
-        if "skip" in rec:
-            emit(f"serve_d{n_dev}", 0.0,
-                 f"skipped;only_{rec['skip']}_devices")
-            continue
-        emit(f"serve_d{n_dev}", rec["wall_s"] * 1e6,
-             f"decisions={rec['decisions']};"
-             f"replan_p50_ms={rec['replan_p50_ms']};"
-             f"replan_p95_ms={rec['replan_p95_ms']};"
-             f"decisions_per_sec={rec['decisions_per_sec']};"
-             f"jobs_completed={rec['completed']}/2")
+    quad, w0, prob = demo_problem(seed=0)
+    feed = synthetic_feed(n_markets=2, n_ticks=ticks, seed=3)
+    jobs = [JobSpec(name=f"job{i}", market=i % 2, eps=0.5, theta=60.0,
+                    n_workers=4) for i in range(2)]
+    cfg = ServeConfig(horizon=horizon, warmup=warmup, score_seeds=2, seed=0,
+                      batch=4, idle_step=0.25, multibid_partitions=((2, 2),),
+                      score_ticks=score_ticks or None)
+    for n_dev in _device_counts([1] if SMOKE else [1, 2, 4]):
+        mesh = make_scenario_mesh(n_dev) if n_dev > 1 else None
+        t0 = time.perf_counter()
+        rep = BidServer(feed, jobs, prob=prob, quad=quad, w0=w0,
+                        alpha=prob.alpha,
+                        rt_true=RuntimeModel(kind="exp", lam=2.0, delta=0.05),
+                        cfg=cfg, mesh=mesh).run()
+        wall = time.perf_counter() - t0
+        s = rep["summary"]
+        completed = sum(j["completed"] for j in s["jobs"].values())
+        emit(f"serve_d{n_dev}", wall * 1e6,
+             f"decisions={s['decisions']};"
+             f"replan_p50_ms={s['replan_p50_ms']};"
+             f"replan_p95_ms={s['replan_p95_ms']};"
+             f"decisions_per_sec={s['decisions_per_sec']};"
+             f"jobs_completed={completed}/2")
         if n_dev == 1:
-            for name, j in rec["jobs"].items():
+            for name, j in s["jobs"].items():
                 emit(f"serve_regret_{name}", 0.0,
                      f"cost={j['cost']};"
                      f"regret_vs_hindsight={j['regret_vs_hindsight']};"
@@ -915,23 +832,11 @@ def bench_zoo():
     Rows: tokens/sec under the mask schedule (completed iterations ×
     global_batch × seq_len / steady-state wall); the cost-vs-loss frontier
     across three fixed-bid levels (per-level final loss vs total spot
-    cost); the bf16 mixed-precision zoo carry on the same grid; and the
-    persistent-jit-cache warm start (cold compile vs re-trace + disk load
-    after `jax.clear_caches()`, both net of a steady-state run)."""
-    import tempfile
-
-    import jax
-
+    cost); and the bf16 mixed-precision zoo carry on the same grid."""
     from repro.configs import ARCHS
     from repro.configs.base import InputShape, JobConfig
-    from repro.launch.jitcache import enable_persistent_cache
     from repro.sim import engine
     from repro.train.trainer import train_zoo
-
-    # cache must be on BEFORE the first compile so the tokens/sec run
-    # doubles as the cold-start sample for the warm-start row
-    cache_dir = tempfile.mkdtemp(prefix="bench_zoo_jitcache_")
-    enable_persistent_cache(cache_dir)
 
     J = 4 if SMOKE else 12
     n_w = 4
@@ -950,9 +855,6 @@ def bench_zoo():
         name=f"b{b:.2f}") for b in levels]
     b_sz, s_len = job.shape.global_batch, job.shape.seq_len
 
-    t0 = time.perf_counter()
-    train_zoo(job, scenarios, seeds=n_seeds, n_ticks=n_ticks)
-    cold_s = time.perf_counter() - t0
     res, us_zoo = _timed(lambda: train_zoo(
         job, scenarios, seeds=n_seeds, n_ticks=n_ticks))
     iters = float(np.nansum(res.iterations))
@@ -987,27 +889,29 @@ def bench_zoo():
          f"final_loss={_nanmean(res16.losses[..., -1]):.3f};"
          f"vs_f32={us_zoo / us16:.2f}x")
 
-    # warm start from the persistent cache: drop the in-memory jit cache,
-    # re-trace the same program, let XLA's compile hit the disk cache
-    steady_s = us_zoo / 1e6
-    jax.clear_caches()
-    t0 = time.perf_counter()
-    train_zoo(job, scenarios, seeds=n_seeds, n_ticks=n_ticks)
-    warm_s = time.perf_counter() - t0
-    emit("zoo_jitcache_warm_start", warm_s * 1e6,
-         f"cold_compile_s={max(cold_s - steady_s, 0):.2f};"
-         f"warm_compile_s={max(warm_s - steady_s, 0):.2f};"
-         f"speedup={max(cold_s - steady_s, 1e-9) / max(warm_s - steady_s, 1e-9):.1f}x")
-
 
 def bench_chaos():
     """Recovery overhead of the supervised durable loop: one unfailed
     supervised run vs the same workload under a seeded fault plan (a
-    mid-chunk SIGKILL plus a corrupted newest-step checkpoint). Both runs
-    share a jit cache-less cold start per attempt, so the overhead column
-    is the honest price of dying twice: restart latency + lost-chunk
-    recompute + fallback restore."""
+    mid-chunk SIGKILL plus a corrupted newest-step checkpoint). Every
+    attempt reads its compiled programs from the persistent compilation
+    cache once the first has filled it, so the overhead column is the
+    price of dying twice: restart latency + re-trace + lost-chunk
+    recompute + fallback restore.
+
+    The supervised workers are child processes that need the device, and
+    a device belongs to the process that first touched it: run this
+    benchmark alone (``--only chaos``)."""
     import tempfile
+
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
+        raise RuntimeError(
+            "bench_chaos starts supervised workers that need the device, "
+            "but this process already holds it (an earlier benchmark ran "
+            "JAX here): run `python -m benchmarks.run --only chaos` on "
+            "its own")
 
     from repro.chaos import Fault, FaultPlan
     from repro.launch import supervisor as sup
@@ -1081,11 +985,15 @@ def main() -> None:
     if args.smoke:
         global SMOKE
         SMOKE = True
-    names = args.only.split(",") if args.only else list(BENCHES)
+    # chaos runs alone (its workers need the device this process takes)
+    names = (args.only.split(",") if args.only
+             else [n for n in BENCHES if n != "chaos"])
     unknown = [n for n in names if n not in BENCHES]
     if unknown:
         ap.error(f"unknown benchmark(s) {','.join(unknown)}; "
                  f"choose from {','.join(BENCHES)}")
+    from repro.launch.jitcache import enable_persistent_cache
+    enable_persistent_cache()
     print("name,us_per_call,derived")
     for n in names:
         BENCHES[n]()

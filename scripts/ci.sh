@@ -26,7 +26,7 @@
 # adapter: engine-vs-plain-loop parity, the weighted_mean convention at the
 # train-step denominator, bf16 checkpoint kill-and-resume) plus the zoo
 # benchmark in --smoke mode (tokens/sec under elastic masking, cost-vs-loss
-# frontier, persistent-jit-cache warm start).
+# frontier, the bf16 carry).
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH}

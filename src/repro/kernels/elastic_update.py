@@ -15,9 +15,10 @@ The megabatched trainer computes gradients of the *sum*-form loss
 
 One kernel launch updates every parameter of every replica: inputs are the
 flat ``(R, P)`` parameter/momentum/gradient blocks plus per-replica scalars
-``w_sum``/``running``/``lr`` (kept as (R, 1) columns so each grid row sees
-its own scalars without gather logic). The grid is (R, P/block): rows are
-independent replicas, blocks stream through VMEM.
+``w_sum``/``running``/``lr`` (kept as (R, 1) columns that broadcast along
+each replica's row). The grid walks P in ``(R, block)`` tiles: every tile
+holds all replicas (a whole array dim) and a lane-aligned slice of P, the
+shapes the TPU compiler accepts, and streams through VMEM.
 
 Validated on CPU with interpret=True against ``ref.elastic_update_reference``
 (see tests/test_megabatch.py); on CPU execution paths the jnp reference is
@@ -32,23 +33,25 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_P = 512
+#: elements per (R, block) tile: 1 MiB of f32 in each streamed buffer
+TILE_ELEMS = 1 << 18
+LANES = 128
 
 
 def _update_kernel(p_ref, v_ref, g_ref, w_ref, run_ref, lr_ref,
                    p_out, v_out, *, momentum: float):
-    w = w_ref[0, 0]
+    w = w_ref[...]                                       # (R, 1)
     # exact 0 on all-preempted; the 1e-6 clamp mirrors train_step's
     # documented grad normalization (max(Σw, 1e-6)) bit-for-bit
     inv = jnp.where(w > 0, 1.0 / jnp.maximum(w, 1e-6), 0.0)
-    run = run_ref[0, 0] > 0
-    lr = lr_ref[0, 0]
-    v = v_ref[0, :]
-    p = p_ref[0, :]
-    v_new = momentum * v + g_ref[0, :] * inv
+    run = run_ref[...] > 0
+    lr = lr_ref[...]
+    v = v_ref[...]
+    p = p_ref[...]
+    v_new = momentum * v + g_ref[...] * inv
     p_new = p - lr * v_new
-    p_out[0, :] = jnp.where(run, p_new, p)
-    v_out[0, :] = jnp.where(run, v_new, v)
+    p_out[...] = jnp.where(run, p_new, p)
+    v_out[...] = jnp.where(run, v_new, v)
 
 
 @functools.partial(jax.jit, static_argnames=("momentum", "block_p",
@@ -56,36 +59,37 @@ def _update_kernel(p_ref, v_ref, g_ref, w_ref, run_ref, lr_ref,
 def elastic_sgd_update(params: jax.Array, mom: jax.Array, grads: jax.Array,
                        w_sum: jax.Array, running: jax.Array, lr: jax.Array,
                        *, momentum: float = 0.9,
-                       block_p: int = DEFAULT_BLOCK_P,
+                       block_p: Optional[int] = None,
                        interpret: Optional[bool] = None,
                        ) -> Tuple[jax.Array, jax.Array]:
     """params/mom/grads: (R, P) f32; w_sum/running/lr: (R,). Returns the
     updated (params, mom). ``grads`` are SUM-form (unnormalized) gradients;
-    the Eq.-(5) division by Σw happens inside the kernel."""
+    the Eq.-(5) division by Σw happens inside the kernel. ``block_p`` (a
+    multiple of 128) overrides the tile width, which by default holds
+    `TILE_ELEMS` elements."""
+    from repro.kernels import auto_interpret
+
     r, p_dim = params.shape
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = auto_interpret(interpret)
+    if block_p is None:
+        block_p = max(LANES, TILE_ELEMS // r // LANES * LANES)
     blk = min(block_p, p_dim)
-    pad = (-p_dim) % blk
-    if pad:
-        widen = lambda x: jnp.pad(x, ((0, 0), (0, pad)))
-        params, mom, grads = widen(params), widen(mom), widen(grads)
     cols = lambda x, dt: x.astype(dt).reshape(r, 1)
     w2 = cols(w_sum, jnp.float32)
     run2 = cols(running, jnp.float32)
     lr2 = cols(lr, jnp.float32)
 
-    row = pl.BlockSpec((1, blk), lambda i, j: (i, j))
-    scal = pl.BlockSpec((1, 1), lambda i, j: (i, 0))
+    tile = pl.BlockSpec((r, blk), lambda j: (0, j))
+    scal = pl.BlockSpec((r, 1), lambda j: (0, 0))
     out_shape = jax.ShapeDtypeStruct(params.shape, params.dtype)
-    p_new, v_new = pl.pallas_call(
+    # a ragged last tile needs no padding copy: the update is elementwise,
+    # and writes past P are dropped; params/mom update in place
+    return pl.pallas_call(
         functools.partial(_update_kernel, momentum=momentum),
-        grid=(r, params.shape[1] // blk),
-        in_specs=[row, row, row, scal, scal, scal],
-        out_specs=(row, row),
+        grid=(pl.cdiv(p_dim, blk),),
+        in_specs=[tile, tile, tile, scal, scal, scal],
+        out_specs=(tile, tile),
         out_shape=(out_shape, out_shape),
+        input_output_aliases={0: 0, 1: 1},
         interpret=interpret,
     )(params, mom, grads, w2, run2, lr2)
-    if pad:
-        p_new, v_new = p_new[:, :p_dim], v_new[:, :p_dim]
-    return p_new, v_new

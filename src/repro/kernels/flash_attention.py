@@ -22,11 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover - pallas tpu always importable in jax>=0.6
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -135,9 +131,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
                                lambda b_, h_, i, j: (b_, h_, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s_pad, d), q.dtype),
         scratch_shapes=[
-            _VMEM((block_q, d), jnp.float32),
-            _VMEM((block_q,), jnp.float32),
-            _VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
         ],
         compiler_params=None,
         interpret=interpret,

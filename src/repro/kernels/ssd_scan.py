@@ -12,61 +12,65 @@ The sequential inter-chunk recurrence (h_c = decay_c·h_{c−1} + state_c) and
 the y_inter = C·h_prev·exp(cs) correction are cheap O(Q·P·N) jnp outside the
 kernel (ops.py). VMEM per cell ≈ Q² + 2·Q·N + 2·Q·P + P·N floats ≈ 0.5 MB at
 (Q,P,N) = (256,64,128); all matmul dims are 128-multiples (Q=256, N=128) or
-the packed-lane 64 (P) — MXU-friendly.
+the packed-lane 64 (P) — MXU-friendly. Every block's last two dims are
+whole array dims, which the TPU compiler requires of blocks that are not
+(8, 128) multiples.
 
 Validated with interpret=True against ref.ssd_reference (naive per-token
 recurrence).
 """
 from __future__ import annotations
 
-import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
-
 
 def _ssd_chunk_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
                       y_ref, state_ref, cs_ref, cdecay_ref):
-    """Grid: (B, H, nc). Blocks: x (Q,P), dt (Q,), a scalar per head,
-    b/c (Q,N) (group-mapped in the index_map)."""
+    """Grid: (B, H, nc). Blocks: x (Q,P), dt (1,Q) row, a (1,1) per head,
+    b/c (Q,N) (group-mapped in the index_map).
+
+    Every per-chunk vector is a (1, Q) lane row, so each block's last two
+    dims are whole array dims (the TPU tiling rule). The prefix sums and
+    the row→column turns are triangular/identity matmuls at full f32
+    precision on the MXU."""
     x = x_ref[0, 0, 0].astype(jnp.float32)               # (Q, P)
-    dt = dt_ref[0, 0, 0].astype(jnp.float32)             # (Q,)
-    a_h = a_ref[0].astype(jnp.float32)                   # ()
+    dt = dt_ref[0, 0, 0].astype(jnp.float32)             # (1, Q)
+    a_h = a_ref[0].astype(jnp.float32)                   # (1, 1)
     bm = b_ref[0, 0, 0].astype(jnp.float32)              # (Q, N)
     cm = c_ref[0, 0, 0].astype(jnp.float32)              # (Q, N)
     q = x.shape[0]
 
-    a = dt * a_h                                         # (Q,) ≤ 0
-    cs = jnp.cumsum(a)                                   # (Q,)
-    seg = cs[:, None] - cs[None, :]                      # cs_i − cs_j
-    tri = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    def dot(l, r, dims):
+        return jax.lax.dot_general(l, r, (dims, ((), ())),
+                                   precision=jax.lax.Precision.HIGHEST,
+                                   preferred_element_type=jnp.float32)
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    tri = row >= col                                     # i ≥ j
+    a = dt * a_h                                         # (1, Q) ≤ 0
+    cs = dot(a, tri.astype(jnp.float32), ((1,), (1,)))   # (1, Q) cumsum
+    cs_col = dot(tri.astype(jnp.float32), a, ((1,), (1,)))   # (Q, 1)
+    dt_col = dot((row == col).astype(jnp.float32), dt, ((1,), (1,)))
+    seg = cs_col - cs                                    # cs_i − cs_j
     decay = jnp.where(tri, jnp.exp(jnp.where(tri, seg, 0.0)), 0.0)
 
-    scores = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-    w = scores * decay * dt[None, :]                     # (Q_i, Q_j)
-    y = jax.lax.dot_general(w, x, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+    scores = dot(cm, bm, ((1,), (1,)))                   # (Q_i, Q_j)
+    w = scores * decay * dt                              # × dt_j
+    y = dot(w, x, ((1,), (0,)))                          # (Q, P)
 
-    last = cs[-1]
-    wstate = jnp.exp(last - cs) * dt                     # (Q,)
-    state = jax.lax.dot_general(bm * wstate[:, None], x,
-                                (((0,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (N, P)
+    last = cs[:, q - 1:]                                 # (1, 1)
+    wstate = jnp.exp(last - cs_col) * dt_col             # (Q, 1)
+    state = dot(bm * wstate, x, ((0,), (0,)))            # (N, P)
 
     y_ref[0, 0, 0] = y.astype(y_ref.dtype)
     state_ref[0, 0, 0] = state
     cs_ref[0, 0, 0] = cs
-    cdecay_ref[0, 0, 0] = jnp.exp(last)[None]
+    cdecay_ref[0, 0, 0] = jnp.exp(last)
 
 
 def ssd_chunk_pallas(xh, dt, a_h, bm, cm, *, chunk: int,
@@ -86,44 +90,45 @@ def ssd_chunk_pallas(xh, dt, a_h, bm, cm, *, chunk: int,
     from repro.kernels import auto_interpret
     interpret = auto_interpret(interpret)
 
-    # layout: (B, H, nc, Q, ...) so the grid walks contiguous blocks
+    # layout: (B, H, nc, Q, ...) so the grid walks contiguous blocks; the
+    # per-token vectors ride as (1, Q) rows
     x_l = xh.transpose(0, 2, 1, 3).reshape(b, h, nc, q, p)
-    dt_l = dt.transpose(0, 2, 1).reshape(b, h, nc, q)
+    dt_l = dt.transpose(0, 2, 1).reshape(b, h, nc, 1, q)
+    a_l = a_h.reshape(h, 1, 1)
     b_l = bm.transpose(0, 2, 1, 3).reshape(b, g, nc, q, n)
     c_l = cm.transpose(0, 2, 1, 3).reshape(b, g, nc, q, n)
 
-    grid = (b, h, nc)
-    kernel = _ssd_chunk_kernel
+    def block(*tail):
+        return pl.BlockSpec((1, 1, 1) + tail,
+                            lambda b_, h_, c_: (b_, h_, c_) + (0,) * len(tail))
+
+    def group_block(*tail):
+        return pl.BlockSpec((1, 1, 1) + tail,
+                            lambda b_, h_, c_: (b_, h_ // rep, c_)
+                            + (0,) * len(tail))
 
     y, states, cs, cdecay = pl.pallas_call(
-        kernel,
-        grid=grid,
+        _ssd_chunk_kernel,
+        grid=(b, h, nc),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, q, p), lambda b_, h_, c_: (b_, h_, c_, 0, 0)),
-            pl.BlockSpec((1, 1, 1, q), lambda b_, h_, c_: (b_, h_, c_, 0)),
-            pl.BlockSpec((1,), lambda b_, h_, c_: (h_,)),
-            pl.BlockSpec((1, 1, 1, q, n),
-                         lambda b_, h_, c_, r=rep: (b_, h_ // r, c_, 0, 0)),
-            pl.BlockSpec((1, 1, 1, q, n),
-                         lambda b_, h_, c_, r=rep: (b_, h_ // r, c_, 0, 0)),
+            block(q, p),
+            block(1, q),
+            pl.BlockSpec((1, 1, 1), lambda b_, h_, c_: (h_, 0, 0)),
+            group_block(q, n),
+            group_block(q, n),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, q, p), lambda b_, h_, c_: (b_, h_, c_, 0, 0)),
-            pl.BlockSpec((1, 1, 1, n, p), lambda b_, h_, c_: (b_, h_, c_, 0, 0)),
-            pl.BlockSpec((1, 1, 1, q), lambda b_, h_, c_: (b_, h_, c_, 0)),
-            pl.BlockSpec((1, 1, 1, 1), lambda b_, h_, c_: (b_, h_, c_, 0)),
-        ],
+        out_specs=[block(q, p), block(n, p), block(1, q), block(1, 1)],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, nc, q, p), xh.dtype),
             jax.ShapeDtypeStruct((b, h, nc, n, p), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, nc, q), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, nc, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, nc, 1, q), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, nc, 1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(x_l, dt_l, a_h, b_l, c_l)
+    )(x_l, dt_l, a_l, b_l, c_l)
 
     y_intra = y.reshape(b, h, s, p).transpose(0, 2, 1, 3)
     states = states.transpose(0, 2, 1, 3, 4)             # (B, nc, H, N, P)
-    cs = cs.transpose(0, 2, 1, 3)                        # (B, nc, H, Q)
-    cdecay = cdecay[..., 0].transpose(0, 2, 1)           # (B, nc, H)
+    cs = cs[..., 0, :].transpose(0, 2, 1, 3)             # (B, nc, H, Q)
+    cdecay = cdecay[..., 0, 0].transpose(0, 2, 1)        # (B, nc, H)
     return y_intra, states, cs, cdecay

@@ -62,19 +62,11 @@ def build_parser() -> argparse.ArgumentParser:
                     help="force N virtual host devices before jax loads")
     ap.add_argument("--json", action="store_true",
                     help="print the full report, not just the summary")
-    ap.add_argument("--jit-cache", nargs="?", const="", default=None,
-                    metavar="DIR",
-                    help="enable the persistent jit compilation cache at "
-                         "DIR (default: launch.jitcache.default_cache_dir)"
-                         " — cold-start replan compiles become disk loads")
     return ap
 
 
 def run(args) -> dict:
     # deferred imports so --devices can force the platform first
-    if getattr(args, "jit_cache", None) is not None:
-        from repro.launch.jitcache import enable_persistent_cache
-        enable_persistent_cache(args.jit_cache or None)
     from repro.core.cost_model import RuntimeModel
     from repro.launch.mesh import make_scenario_mesh
     from repro.service import (BidServer, JobSpec, ServeConfig,
@@ -116,6 +108,8 @@ def main(argv=None) -> int:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices} "
             + os.environ.get("XLA_FLAGS", "")).strip()
+    from repro.launch.jitcache import enable_persistent_cache
+    enable_persistent_cache()
     report = run(args)
     print(json.dumps(report if args.json else report["summary"], indent=2))
     return 0

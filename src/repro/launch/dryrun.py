@@ -201,6 +201,8 @@ def main():
                     help="decode cache sharding (see EXPERIMENTS.md §Perf)")
     ap.add_argument("--out", default=None, help="JSON output path prefix")
     args = ap.parse_args()
+    from repro.launch.jitcache import enable_persistent_cache
+    enable_persistent_cache()
 
     combos = ([(a, s) for a in sorted(ARCHS) for s in
                ["train_4k", "prefill_32k", "decode_32k", "long_500k"]]

@@ -1,52 +1,32 @@
-"""Shared persistent-jit-cache policy for every launch entry point.
+"""Persistent-compilation-cache policy shared by every entry point.
 
-The engine's programs are big scans: a cold-start compile of the batched
-train program costs seconds to minutes, and it used to be paid per process
-— every supervisor restart, every `launch/train.py` invocation, every
-bidding-service window warm-up. jax's persistent compilation cache turns
-each re-trace of an identical program into a disk load; this module is the
-one place that policy lives so `launch/train.py`, `launch/bidserve.py`,
-and the supervisor's worker all behave the same (previously the supervisor
-carried its own inline copy).
+A cold compile of the engine's training scan costs seconds on the CPU and
+minutes at published widths on the chip, and every supervisor restart or
+repeat invocation used to pay it again. JAX's persistent compilation cache
+turns a re-trace of an identical program into a disk load. The cache's
+path is part of its key, so it lives at one fixed place: where
+``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads that itself and nothing is
+configured here; otherwise it is ``<checkout>/.jax_cache`` (git-ignored).
 
-Call `enable_persistent_cache` BEFORE the first jit execution (it only
-configures `jax.config`, so importing jax first is fine). Run-scoped
-directories (`cache_dir_for_run`) keep a supervised run's cache inside its
-``run_dir``; the cross-run default lands under ``~/.cache`` (override with
-``REPRO_JIT_CACHE``).
+Call `enable_persistent_cache` before the first compile. It only updates
+``jax.config``, so it never initialises a backend.
 """
 from __future__ import annotations
 
 import os
-from typing import Optional
 
-#: environment override for the cross-run default cache location
-ENV_VAR = "REPRO_JIT_CACHE"
-
-
-def default_cache_dir() -> str:
-    return os.environ.get(ENV_VAR) or os.path.join(
-        os.path.expanduser("~"), ".cache", "repro", "jax_cache")
+#: the fixed cache location when JAX_COMPILATION_CACHE_DIR is unset
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
 
-def cache_dir_for_run(run_dir: str) -> str:
-    """The per-run cache location (inside the run directory, so a run's
-    artifacts — spec, checkpoints, events, compiled programs — travel and
-    get cleaned up together)."""
-    return os.path.join(run_dir, "jax_cache")
-
-
-def enable_persistent_cache(cache_dir: Optional[str] = None,
-                            min_compile_secs: float = 0.0) -> str:
-    """Point jax's persistent compilation cache at ``cache_dir`` (created
-    on demand by jax) and compile-time-threshold ``min_compile_secs``
-    (0 caches everything — right for engine scans, whose every compile is
-    worth a disk hit). Returns the directory used. Idempotent; safe to
-    call from several entry points in one process."""
+def enable_persistent_cache() -> str:
+    """Turn the persistent compilation cache on; returns its directory."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
     import jax
 
-    cache_dir = cache_dir or default_cache_dir()
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      float(min_compile_secs))
-    return cache_dir
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    return CHECKOUT_CACHE_DIR

@@ -3,6 +3,15 @@ importing this module never touches jax device state."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the engine and the models
+    place data with ``shard_map`` and sharding constraints, not with the
+    ``Explicit`` axes ``make_mesh`` defaults to."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -10,12 +19,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     (512 chips). Axes: ("data", "model") / ("pod", "data", "model")."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh for CPU tests (1×1)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _mesh((1, 1), ("data", "model"))
 
 
 def make_scenario_mesh(n_devices: int | None = None):
@@ -23,12 +32,13 @@ def make_scenario_mesh(n_devices: int | None = None):
 
     The single axis is named ``data`` — `sim.engine.simulate_sharded`
     partitions the leading scenario axis of the stacked grid across it.
-    Defaults to every visible device; on a CPU host, force N virtual
-    devices with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
-    before jax initializes (``scripts/ci.sh --devices N`` does this)."""
+    Spans the first ``n_devices`` visible devices (default: all of them);
+    on a CPU host, force N virtual devices with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before jax
+    initializes (``scripts/ci.sh --devices N`` does this)."""
     if n_devices is None:
         n_devices = jax.device_count()
-    return jax.make_mesh((n_devices,), ("data",))
+    return _mesh((n_devices,), ("data",), jax.devices()[:n_devices])
 
 
 def make_scenario_replica_mesh(n_scenario: int | None = None,
@@ -48,7 +58,8 @@ def make_scenario_replica_mesh(n_scenario: int | None = None,
             f"mesh shape ({n_scenario}, {n_replica}) needs "
             f"{n_scenario * n_replica} devices but only {total} are "
             "visible")
-    return jax.make_mesh((n_scenario, n_replica), ("data", "replica"))
+    return _mesh((n_scenario, n_replica), ("data", "replica"),
+                 jax.devices()[:n_scenario * n_replica])
 
 
 def data_parallel_workers(mesh) -> int:

@@ -40,6 +40,8 @@ def main():
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.launch.jitcache import enable_persistent_cache
+    enable_persistent_cache()
 
     cfg = get_config(args.arch).reduced()
     key = jax.random.PRNGKey(args.seed)

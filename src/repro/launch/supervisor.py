@@ -26,7 +26,6 @@ Layout of a run directory::
       fired.json           fired-fault ledger (shared: worker + supervisor)
       heartbeat.json       {"tick", "time", "pid", "phase"}, atomic
       ckpt/step_*/         step-directory checkpoints (keep_last GC'd)
-      jax_cache/           persistent jit cache (restart compiles ~3x faster)
       result.json          written by the worker on success
       worker_events.jsonl  injected faults + NaN rollbacks, as they happen
       attempt_{k}.log      worker stdout+stderr per attempt
@@ -170,14 +169,13 @@ def worker_main(run_dir: str) -> int:
     spec = WorkerSpec.load(os.path.join(run_dir, SPEC_NAME))
 
     import jax
-    if spec.jit_cache:
-        # restarts re-trace the same chunk programs; the persistent cache
-        # turns each restart's compile into a disk load
-        from repro.launch.jitcache import (cache_dir_for_run,
-                                           enable_persistent_cache)
-        enable_persistent_cache(cache_dir_for_run(run_dir))
 
+    from repro.launch.jitcache import enable_persistent_cache
     from repro.train import trainer
+
+    # restarts re-trace the same chunk programs; the persistent cache
+    # turns each restart's compile into a disk load
+    enable_persistent_cache()
 
     job, scenarios, seeds = build_workload(spec)
 

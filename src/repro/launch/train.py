@@ -108,11 +108,6 @@ def main():
                     help="train through the zoo↔engine adapter "
                          "(trainer.train_zoo: mixed-precision carries, "
                          "bf16 checkpoints) in the supervised worker")
-    ap.add_argument("--jit-cache", nargs="?", const="", default=None,
-                    metavar="DIR",
-                    help="enable the persistent jit compilation cache at "
-                         "DIR (default: launch.jitcache.default_cache_dir)"
-                         " so repeat invocations skip cold-start compiles")
     ap.add_argument("--local", action="store_true",
                     help="reduced config + simulated market on this host")
     ap.add_argument("--strategy", default="optimal-two-bids",
@@ -181,9 +176,8 @@ def main():
     if args.param_dtype and args.param_dtype not in ("float32", "fp32",
                                                      "f32"):
         args.zoo = True           # mixed precision needs the zoo carry
-    if args.jit_cache is not None:
-        from repro.launch.jitcache import enable_persistent_cache
-        enable_persistent_cache(args.jit_cache or None)
+    from repro.launch.jitcache import enable_persistent_cache
+    enable_persistent_cache()
     if args.supervise:
         if args.run_dir is None:
             ap.error("--supervise requires --run-dir")

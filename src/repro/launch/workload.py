@@ -67,7 +67,6 @@ class WorkerSpec:
     keep_last: int = 3
     mesh: int = 0
     async_save: bool = False
-    jit_cache: bool = True
     seed: int = 0
 
     def __post_init__(self):

@@ -30,19 +30,6 @@ from repro.models.common import (
     dense_spec,
 )
 
-try:  # jax >= 0.6
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-import inspect
-
-# the "don't check replication" kwarg was renamed check_rep → check_vma
-_SHMAP_NO_CHECK = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(shard_map).parameters
-    else {"check_rep": False})
-
 
 def moe_defs(cfg):
     m = cfg.moe
@@ -232,10 +219,10 @@ def moe_block(p, cfg, x) -> Tuple[jax.Array, jax.Array]:
     else:
         fn_wrapped = fn
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         fn_wrapped, mesh=mesh,
         in_specs=(x_spec, p_specs),
         out_specs=(x_spec, P()),
-        **_SHMAP_NO_CHECK,
+        check_vma=False,
     )(x, {k: p[k] for k in p_specs})
     return y, aux

@@ -46,13 +46,9 @@ _OP_RE = re.compile(
 
 
 def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` across jax versions: newer jax returns
-    one dict, older versions a per-device list of dicts — normalize to the
-    (single-program) dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """``compiled.cost_analysis()``, or an empty dict where the backend
+    reports none."""
+    return compiled.cost_analysis() or {}
 
 
 def _shape_bytes(shape_str: str) -> int:

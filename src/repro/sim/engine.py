@@ -55,7 +55,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import inspect
 from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence
 
 import numpy as np
@@ -64,18 +63,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.scipy.special import ndtr, ndtri
-from jax.sharding import PartitionSpec
-
-try:  # jax >= 0.6
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the "don't check replication" kwarg was renamed check_rep → check_vma
-_SHMAP_NO_CHECK = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else {"check_rep": False})
+from jax.sharding import NamedSharding, PartitionSpec
 
 # The pad value for absent workers in stacked bid schedules lives with the
 # strategies (which build the schedules); re-exported here for engine users.
@@ -456,6 +444,12 @@ def stack_scenarios(scenarios: Sequence[Scenario]) -> ScenarioBatch:
 # --------------------------------------------------------------------------
 
 
+#: the oracle's products run at full float32 precision: an accelerator's
+#: default single bf16 pass would move the paper's error trajectories off
+#: the float64 legacy loop they are checked against
+_F32 = lax.Precision.HIGHEST
+
+
 class JaxQuadratic(NamedTuple):
     """Device-side view of data.synthetic.QuadraticProblem. The quadratic is
     exact, so error = G(w) − G* = ½ (w−w*)ᵀ H (w−w*) — no residual pass."""
@@ -471,18 +465,19 @@ class JaxQuadratic(NamedTuple):
 
     def error(self, w: jnp.ndarray) -> jnp.ndarray:
         d = w - self.w_star
-        return 0.5 * d @ (self.H @ d)
+        return 0.5 * jnp.dot(d, jnp.dot(self.H, d, precision=_F32),
+                             precision=_F32)
 
     def full_grad(self, w: jnp.ndarray) -> jnp.ndarray:
-        return self.H @ (w - self.w_star)
+        return jnp.dot(self.H, w - self.w_star, precision=_F32)
 
     def minibatch_grads(self, key, w: jnp.ndarray, n_workers: int,
                         batch: int) -> jnp.ndarray:
         """Per-worker minibatch gradients, shape (n_workers, d)."""
         idx = jax.random.randint(key, (n_workers, batch), 0, self.n_samples)
         a = self.A[idx]                                  # (n, b, d, d)
-        r = jnp.einsum("wbij,j->wbi", a, w) - self.b[idx]
-        return jnp.einsum("wbij,wbi->wj", a, r) / batch
+        r = jnp.einsum("wbij,j->wbi", a, w, precision=_F32) - self.b[idx]
+        return jnp.einsum("wbij,wbi->wj", a, r, precision=_F32) / batch
 
 
 def jax_quadratic(quad) -> JaxQuadratic:
@@ -640,7 +635,7 @@ def assert_carry_dtypes(state: SimState) -> None:
 
 
 def initial_state(scenarios: "ScenarioBatch | Sequence[Scenario]", model0,
-                  n_seeds: int) -> SimState:
+                  n_seeds: int, sharding=None) -> SimState:
     """The batched (S, R) initial scan carry: every (scenario, seed) replica
     starts from ``model0`` at t=0 with empty trajectories.
 
@@ -650,27 +645,35 @@ def initial_state(scenarios: "ScenarioBatch | Sequence[Scenario]", model0,
 
     The model fan-out is materialized eagerly (``broadcast_to`` on device)
     so the buffers exactly match the scan carry — a donated call reuses
-    them in place. For a non-donated call this is a transient extra
-    (S, R)-replica copy at startup; at the reduced-model scales this repo
-    runs that is cheap, and huge grids should donate anyway."""
+    them in place. ``sharding`` (a `NamedSharding` over the (S, R) grid
+    axes) builds every leaf's shards on their own devices from a copy of
+    ``model0``, so no device ever holds more than its own shard of the
+    grid."""
     if not isinstance(scenarios, ScenarioBatch):
         scenarios = stack_scenarios(scenarios)
     grid = (scenarios.n_scenarios, int(n_seeds))
     j_max = scenarios.j_max
-    model = jax.tree.map(
-        lambda x: jnp.broadcast_to(jnp.asarray(x), grid + jnp.shape(x)),
-        canonicalize_model(model0))
 
-    def nan_traj():
-        return jnp.full(grid + (j_max,), jnp.nan, jnp.float32)
+    def fan_out(x):
+        shape = grid + x.shape
+        if sharding is None:
+            return jnp.broadcast_to(x, shape)
+        shard = sharding.shard_shape(shape)
+        return jax.make_array_from_single_device_arrays(
+            shape, sharding,
+            [jnp.broadcast_to(jax.device_put(x, d), shard)
+             for d in sharding.addressable_devices_indices_map(shape)])
 
+    nan_traj = jnp.full((j_max,), jnp.nan, jnp.float32)
     return SimState(
-        t=jnp.zeros(grid, jnp.float32), j=jnp.zeros(grid, jnp.int32),
-        bucket=jnp.full(grid, -1, jnp.int32),
-        total_cost=jnp.zeros(grid, jnp.float32),
-        total_idle=jnp.zeros(grid, jnp.float32), model=model,
-        err_traj=nan_traj(), cost_traj=nan_traj(),
-        time_traj=nan_traj(), y_traj=nan_traj())
+        t=fan_out(jnp.zeros((), jnp.float32)),
+        j=fan_out(jnp.zeros((), jnp.int32)),
+        bucket=fan_out(jnp.full((), -1, jnp.int32)),
+        total_cost=fan_out(jnp.zeros((), jnp.float32)),
+        total_idle=fan_out(jnp.zeros((), jnp.float32)),
+        model=jax.tree.map(fan_out, canonicalize_model(model0)),
+        err_traj=fan_out(nan_traj), cost_traj=fan_out(nan_traj),
+        time_traj=fan_out(nan_traj), y_traj=fan_out(nan_traj))
 
 
 @dataclasses.dataclass
@@ -687,7 +690,8 @@ class EngineResult:
     total_cost: np.ndarray       # (S, R)
     total_idle: np.ndarray       # (S, R)
     J: np.ndarray                # (S,) per-scenario targets
-    final_model: Any = None      # device pytree, leaves stacked (S, R, ...)
+    final_state: Optional[SimState] = None  # device carry after the run,
+    #                              leaves (S, R, ...) — a resume point
     snapshots: Any = None        # SimState pytree, leaves (S, R, n_snap, …)
     #                              — the full carry every cfg.snapshot_every
     #                              ticks (None when snapshots are off)
@@ -695,6 +699,11 @@ class EngineResult:
     #                              snapshot i is the carry after tick
     #                              snapshot_ticks[i] (resume passes this as
     #                              tick0)
+
+    @property
+    def final_model(self) -> Any:
+        """The trained model pytree, leaves stacked (S, R, ...)."""
+        return None if self.final_state is None else self.final_state.model
 
     @property
     def losses(self) -> np.ndarray:
@@ -1025,7 +1034,9 @@ def simulate_program(scenarios, program: ModelProgram, model0, data, seeds,
     data: device pytree visible to every step (problem constants / stacked
     batches); seeds: int count or explicit sequence. With ``donate=True``
     the initial-carry buffers are donated to the call (pass a fresh copy if
-    you need them afterwards).
+    you need them afterwards). The engine drops its own reference to
+    ``model0`` once the carry is built, so a caller that passes a fresh
+    model without keeping it never holds two copies on the device.
 
     Checkpointing: ``cfg.snapshot_every = k`` stacks the full scan carry
     every k ticks into ``EngineResult.snapshots`` (+ ``snapshot_ticks``);
@@ -1051,6 +1062,7 @@ def simulate_program(scenarios, program: ModelProgram, model0, data, seeds,
     n_run = _check_run_window(cfg, tick0)
     if init_state is None:
         init_state = initial_state(scenarios, model0, len(seeds))
+    del model0
     fn = _simulate_jit_donated if donate else _simulate_jit
     final, snaps = fn(scenarios, init_state, data, seeds,
                       jnp.asarray(tick0, jnp.int32), program, n_run,
@@ -1092,7 +1104,7 @@ def _engine_result(final: SimState, snaps, scenarios: ScenarioBatch,
         total_cost=np.asarray(final.total_cost),
         total_idle=np.asarray(final.total_idle),
         J=np.asarray(scenarios.J),
-        final_model=final.model,
+        final_state=final,
         snapshots=snaps,
         snapshot_ticks=snap_ticks)
 
@@ -1113,19 +1125,21 @@ def _pad_axis(x: jnp.ndarray, axis: int, target: int) -> jnp.ndarray:
     return jnp.concatenate([x, jnp.take(x, idx, axis=axis)], axis=axis)
 
 
-def _padded_size(n: int, shards: int) -> int:
+def _padded_size(n: int, shards: int, platform: str) -> int:
     """Rows after padding ``n`` across ``shards`` devices: the smallest
-    multiple of ``shards`` that is ≥ n AND gives every shard ≥ 2 rows.
+    multiple of ``shards`` that is ≥ n — and, on the CPU, that gives every
+    shard ≥ 2 rows.
 
-    The ≥ 2 floor is the bit-exactness envelope: XLA:CPU compiles a
+    The CPU's ≥ 2 floor is its bit-exactness envelope: XLA:CPU compiles a
     size-1 vmap lane's dots/einsums with a different contraction order
     than the same cell inside a wider batch (observed ~1e-7 drift), while
-    every width ≥ 2 reproduces the unsharded path bit-for-bit. Padding a
-    1-row shard up to 2 costs one duplicated cell and keeps the sharded
-    path exactly pinned to the vmapped one."""
+    every width ≥ 2 reproduces the unsharded path bit-for-bit. On an
+    accelerator the floor would double the models each chip holds, so it
+    applies to the CPU only."""
     if shards <= 1:
         return n
-    return shards * max(2, -(-n // shards))
+    floor = 2 if platform == "cpu" else 1
+    return shards * max(floor, -(-n // shards))
 
 
 def _mesh_axis_size(mesh, name: str) -> int:
@@ -1147,11 +1161,11 @@ def _sharded_sim(batch, state0, data, seeds, tick0, mesh, program, n_run,
     def local(b, st, d, sd, t0):
         return _vmapped_sim(b, st, d, sd, t0, program, n_run, k_snap)
 
-    return _shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(sspec, gspec, PartitionSpec(), seedspec,
                   PartitionSpec()),
-        out_specs=(gspec, gspec), **_SHMAP_NO_CHECK)(
+        out_specs=(gspec, gspec), check_vma=False)(
             batch, state0, data, seeds, tick0)
 
 
@@ -1188,9 +1202,10 @@ def simulate_sharded(scenarios, program: ModelProgram, model0, data, seeds,
     absolute tick index — never a device or shard position — so a sharded
     run is bit-identical to the single-device vmapped path, snapshots
     included. Non-divisible grids are handled by padding each sharded axis
-    (repeating the last row) to a multiple of the axis size with at least
-    2 rows per shard (see `_padded_size` for why 2), and slicing the
-    padding back off the results.
+    (repeating the last row) to a multiple of the axis size — with at
+    least 2 rows per shard on the CPU (see `_padded_size` for why) — and
+    slicing the padding back off the results. A fresh grid is built shard
+    by shard on its own devices (`initial_state`'s ``sharding``).
 
     ``mesh``: a `jax.sharding.Mesh` whose sharded axes are named ``data``
     (scenarios) and/or ``replica`` (seeds) — `repro.launch.mesh` has
@@ -1222,17 +1237,21 @@ def simulate_sharded(scenarios, program: ModelProgram, model0, data, seeds,
     tick0 = int(tick0)
     n_run = _check_run_window(cfg, tick0)
     S, R = scenarios.n_scenarios, len(seeds)
-    s_pad = _padded_size(S, _mesh_axis_size(mesh, "data"))
-    r_pad = _padded_size(R, _mesh_axis_size(mesh, "replica"))
+    platform = mesh.devices.flat[0].platform
+    s_pad = _padded_size(S, _mesh_axis_size(mesh, "data"), platform)
+    r_pad = _padded_size(R, _mesh_axis_size(mesh, "replica"), platform)
     batch_p = (scenarios if s_pad == S else
                jax.tree.map(lambda x: _pad_axis(x, 0, s_pad), scenarios))
     seeds_p = _pad_axis(seeds, 0, r_pad)
     if init_state is None:
-        state0 = initial_state(batch_p, model0, r_pad)
+        state0 = initial_state(batch_p, model0, r_pad,
+                               sharding=NamedSharding(mesh,
+                                                      _grid_specs(mesh)[1]))
     else:
         state0 = jax.tree.map(
             lambda x: _pad_axis(_pad_axis(x, 0, s_pad), 1, r_pad),
             init_state)
+    del model0
     fn = _simulate_sharded_jit_donated if donate else _simulate_sharded_jit
     final, snaps = fn(batch_p, state0, data, seeds_p,
                       jnp.asarray(tick0, jnp.int32), mesh, program, n_run,

@@ -454,6 +454,15 @@ def retry_io(fn: Callable, *args, retries: int = 3, backoff: float = 0.05,
             sleep(backoff * (2 ** attempt))
 
 
+def _resolved(save_fn: Callable) -> Callable:
+    """``save_fn`` taking its state argument as a value or as a zero-
+    argument callable that builds it."""
+    def run(path, state, *args):
+        return save_fn(path, state() if callable(state) else state, *args)
+
+    return run
+
+
 class AsyncCheckpointWriter:
     """Serializes checkpoints on a background thread so the training scan
     never blocks on disk I/O.
@@ -509,12 +518,15 @@ class AsyncCheckpointWriter:
     def submit(self, path: str, state: Any, step: int,
                n_shards: Optional[int] = None) -> None:
         """Enqueue a save of `state` (sharded when `n_shards`); returns
-        without waiting for the write."""
+        without waiting for the write. ``state`` may be a zero-argument
+        callable that builds it, which then runs in the writer's thread
+        (e.g. slicing one snapshot out of a stacked stream)."""
         self._check()
         if n_shards:
-            self._q.put((save_sharded, (path, state, step, n_shards)))
+            self._q.put((_resolved(save_sharded),
+                         (path, state, step, n_shards)))
         else:
-            self._q.put((save, (path, state, step)))
+            self._q.put((_resolved(save), (path, state, step)))
 
     def submit_step(self, root: str, state: Any, tick: int,
                     n_shards: Optional[int] = None,

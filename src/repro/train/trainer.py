@@ -354,7 +354,7 @@ def train_batched(job: JobConfig,
                   use_fused_update: bool = False,
                   mesh=None,
                   program=None,
-                  model0=None) -> engine.EngineResult:
+                  init_model=None) -> engine.EngineResult:
     """Train a real model under every scenario × seed in one compiled call.
 
     Folds the elastic masked train step into the batched engine: the whole
@@ -388,10 +388,13 @@ def train_batched(job: JobConfig,
     back. ``use_fused_update`` additionally routes the elastic SGD apply
     through the fused Pallas kernel (`kernels.ops.fused_elastic_update`).
 
-    ``program`` / ``model0`` swap in a caller-built ModelProgram factory
-    (``n_batches -> ModelProgram``) and matching initial model carry —
-    the hook `train_zoo` uses to run full zoo configs (mixed-precision
-    carries included) through this exact machinery.
+    ``program`` / ``init_model`` swap in a caller-built ModelProgram
+    factory (``n_batches -> ModelProgram``) and a zero-argument builder of
+    the matching initial model carry — the hook `train_zoo` uses to run
+    full zoo configs (mixed-precision carries included) through this exact
+    machinery. The initial model is built only when the grid's carry is,
+    and nothing keeps it afterwards: at published widths one model is a
+    large share of a chip's memory.
 
     ``mesh`` routes execution through `engine.simulate_sharded`: the
     scenario axis of the grid shards over the mesh's ``data`` axis and
@@ -404,24 +407,29 @@ def train_batched(job: JobConfig,
         job, scenarios, n_ticks=n_ticks, n_batches=n_batches,
         batch_fn=batch_fn, batch_seed=batch_seed, megabatch=megabatch,
         use_fused_update=use_fused_update, program=program)
-    if init_state is not None:
-        model0 = None
-    elif model0 is not None:
-        pass                     # caller-built carry (e.g. train_zoo)
-    elif megabatch:
-        model0 = megabatch_mod.init_megabatch_state(
-            job.model, job, jax.random.PRNGKey(job.seed))
-    else:
-        model0 = init_train_state(job.model, job,
-                                  jax.random.PRNGKey(job.seed))
+    init_model = init_model or _default_init(job, megabatch)
     cfg = engine.SimConfig(n_ticks=n_ticks, snapshot_every=snapshot_every)
+    fresh = init_state is None
+    # the initial model is built in the call so that only the engine holds
+    # it, and drops it once the grid's carry is built
     if mesh is not None:
-        return engine.simulate_sharded(scenarios, program, model0, data,
-                                       seeds, cfg, mesh=mesh, donate=donate,
-                                       init_state=init_state, tick0=tick0)
-    return engine.simulate_program(scenarios, program, model0, data, seeds,
-                                   cfg, donate=donate,
-                                   init_state=init_state, tick0=tick0)
+        return engine.simulate_sharded(
+            scenarios, program, init_model() if fresh else None, data,
+            seeds, cfg, mesh=mesh, donate=donate, init_state=init_state,
+            tick0=tick0)
+    return engine.simulate_program(
+        scenarios, program, init_model() if fresh else None, data, seeds,
+        cfg, donate=donate, init_state=init_state, tick0=tick0)
+
+
+def _default_init(job: JobConfig, megabatch: bool = False):
+    """The zero-argument initial-model builder of the reduced-model
+    programs: `init_train_state`, or the flat replica-blocked carry."""
+    key = jax.random.PRNGKey(job.seed)
+    if megabatch:
+        return lambda: megabatch_mod.init_megabatch_state(job.model, job,
+                                                          key)
+    return lambda: init_train_state(job.model, job, key)
 
 
 def _prepare_batched(job: JobConfig, scenarios, *, n_ticks, n_batches,
@@ -461,23 +469,24 @@ def batched_init_state(job: JobConfig,
                                         Sequence[engine.Scenario]],
                        seeds: Union[int, Sequence[int]],
                        megabatch: bool = False,
-                       model0=None) -> engine.SimState:
+                       init_model=None) -> engine.SimState:
     """The (S, R) initial carry a batched training run starts from — and
     therefore the *restore template* for `checkpoint.restore` (same model
     init ``PRNGKey(job.seed)``, same trajectory shapes). ``megabatch`` /
-    ``model0`` must match the run being restored: the flat replica-blocked
-    carry, the (params, opt_state) tree, and a zoo mixed-precision carry
-    are all different pytrees."""
+    ``init_model`` must match the run being restored: the flat replica-
+    blocked carry, the (params, opt_state) tree, and a zoo mixed-precision
+    carry are all different pytrees."""
     n_seeds = int(seeds) if np.isscalar(seeds) else len(seeds)
-    if model0 is not None:
-        pass
-    elif megabatch:
-        model0 = megabatch_mod.init_megabatch_state(
-            job.model, job, jax.random.PRNGKey(job.seed))
-    else:
-        model0 = init_train_state(job.model, job,
-                                  jax.random.PRNGKey(job.seed))
-    return engine.initial_state(scenarios, model0, n_seeds)
+    init_model = init_model or _default_init(job, megabatch)
+    return engine.initial_state(scenarios, init_model(), n_seeds)
+
+
+def _restore_template(job: JobConfig, scenarios, seeds,
+                      megabatch: bool = False, init_model=None):
+    """`batched_init_state`'s shapes and dtypes, built without touching a
+    device — all a restore needs of its template."""
+    return jax.eval_shape(lambda: batched_init_state(
+        job, scenarios, seeds, megabatch=megabatch, init_model=init_model))
 
 
 def save_batched(path: str, result: engine.EngineResult,
@@ -497,10 +506,15 @@ def save_batched(path: str, result: engine.EngineResult,
     `AsyncCheckpointWriter` background thread — the call returns as soon
     as the snapshot is enqueued (do not donate the result's buffers
     before ``writer.wait()``)."""
+    if writer is not None and result.snapshots is not None:
+        # the snapshot is sliced out of the stream in the writer's thread,
+        # so the submit only enqueues
+        tick = int(result.snapshot_ticks[index])
+        writer.submit(path, lambda: engine.snapshot_state(result, index)[0],
+                      tick, n_shards=shards)
+        return tick
     state, tick = engine.snapshot_state(result, index)
-    if writer is not None:
-        writer.submit(path, state, tick, n_shards=shards)
-    elif shards:
+    if shards:
         ckpt_mod.save_sharded(path, state, tick, shards)
     else:
         ckpt_mod.save(path, state, tick)
@@ -512,41 +526,38 @@ def restore_batched(path: str, job: JobConfig,
                                      Sequence[engine.Scenario]],
                     seeds: Union[int, Sequence[int]],
                     megabatch: bool = False,
-                    model0=None):
+                    init_model=None):
     """Load a `save_batched` checkpoint back into a batched carry. Returns
     ``(state, tick)`` for ``train_batched(init_state=state, tick0=tick)``;
     raises a key-naming ValueError if the job/scenario grid drifted from
     the one that was checkpointed. Pass ``megabatch=True`` for checkpoints
     written by a megabatched run (flat replica-blocked carry), or
-    ``model0`` for a caller-built carry (zoo runs — see `resume_zoo`).
+    ``init_model`` for a caller-built carry (zoo runs — see
+    `resume_zoo`).
 
     Both checkpoint formats are accepted (flat .npz or sharded manifest,
     sniffed by `checkpoint.restore_any`), and neither records a mesh: a
     grid saved from an 8-device run resumes on 4 devices, 1 device, or
     the plain vmapped path bit-exactly — re-sharding is just
     ``train_batched(init_state=..., mesh=...)`` on the new mesh."""
-    like = batched_init_state(job, scenarios, seeds, megabatch=megabatch,
-                              model0=model0)
+    like = _restore_template(job, scenarios, seeds, megabatch=megabatch,
+                             init_model=init_model)
     return ckpt_mod.restore_any(path, like)
 
 
 def state_is_finite(state: engine.SimState) -> bool:
     """The in-scan NaN guard's predicate: every float leaf of the carry's
     model, plus the cost/clock accumulators, is finite. (Trajectory
-    buffers are excluded — their not-yet-run entries are NaN by design.)"""
-    for leaf in jax.tree.leaves(state.model):
-        # jnp.issubdtype, not np: ml_dtypes' bfloat16 is NOT a np.floating
-        # subtype, so the numpy predicate would silently skip exactly the
-        # mixed-precision leaves this guard exists to check
-        if not jnp.issubdtype(jnp.asarray(leaf).dtype, jnp.floating):
-            continue
-        arr = np.asarray(leaf)
-        if arr.dtype == np.dtype(jnp.bfloat16):
-            arr = arr.astype(np.float32)
-        if not np.isfinite(arr).all():
-            return False
-    return bool(np.isfinite(np.asarray(state.total_cost)).all()
-                and np.isfinite(np.asarray(state.t)).all())
+    buffers are excluded — their not-yet-run entries are NaN by design.)
+    Reduced where the carry lives, so a device carry is never copied to
+    the host for the check."""
+    # jnp.issubdtype, not np: ml_dtypes' bfloat16 is NOT a np.floating
+    # subtype, so the numpy predicate would silently skip exactly the
+    # mixed-precision leaves this guard exists to check
+    leaves = [x for x in jax.tree.leaves(state.model)
+              if jnp.issubdtype(jnp.asarray(x).dtype, jnp.floating)]
+    return all(bool(jnp.isfinite(x).all())
+               for x in leaves + [state.total_cost, state.t])
 
 
 def train_batched_durable(job: JobConfig,
@@ -569,7 +580,7 @@ def train_batched_durable(job: JobConfig,
                           max_rollbacks: int = 3,
                           hooks=None,
                           program=None,
-                          model0=None) -> engine.EngineResult:
+                          init_model=None) -> engine.EngineResult:
     """Preemption-*durable* batched training: the scan executes in
     ``save_every``-tick jitted chunks on the host, persisting the full
     batched carry to ``checkpoint_path`` after every chunk — so a process
@@ -592,8 +603,13 @@ def train_batched_durable(job: JobConfig,
     instead of one flat .npz; ``async_save=True`` hands serialization to
     a background `AsyncCheckpointWriter` thread so the next chunk's scan
     launches without waiting for disk — the last write is always joined
-    (and its errors surfaced) before the function returns. The loop never
-    donates the carry, so the enqueued snapshot stays consistent.
+    (and its errors surfaced) before the function returns.
+
+    Each chunk donates the carry to its call and emits no snapshot: the
+    chunk's final carry is the checkpoint. So the device holds one carry
+    at a time, which is what lets a published-width model train durably
+    on one chip. What outlives a chunk on the host — the async writer's
+    snapshot, the NaN guard's rollback point — is a host copy.
 
     ``keep_last=n`` switches checkpointing to *step-directory* mode:
     ``checkpoint_path`` names a root directory holding one
@@ -605,7 +621,8 @@ def train_batched_durable(job: JobConfig,
 
     ``nan_guard=True`` validates the carry after every chunk
     (`state_is_finite`): a non-finite model/cost rolls the carry back to
-    the chunk's start and re-runs it, never checkpointing poison; more
+    the chunk's start (kept as a host copy) and re-runs it, never
+    checkpointing poison; more
     than ``max_rollbacks`` consecutive failures raise ``FloatingPointError``.
 
     ``hooks`` is an optional object observing (and, for fault injection,
@@ -630,30 +647,32 @@ def train_batched_durable(job: JobConfig,
     step_mode = keep_last is not None
     resumed_from = None
     if resume and step_mode and ckpt_mod.list_steps(checkpoint_path):
-        like = batched_init_state(job, scenarios, seeds, model0=model0)
+        like = _restore_template(job, scenarios, seeds,
+                                 init_model=init_model)
         state, tick, resumed_from = ckpt_mod.restore_newest(
             checkpoint_path, like, strict=strict_resume)
     elif resume and not step_mode and os.path.exists(checkpoint_path):
         state, tick = restore_batched(checkpoint_path, job, scenarios,
-                                      seeds, model0=model0)
+                                      seeds, init_model=init_model)
         resumed_from = checkpoint_path
     else:
         state, tick = batched_init_state(job, scenarios, seeds,
-                                         model0=model0), 0
+                                         init_model=init_model), 0
     if tick > n_ticks:
         raise ValueError(
             f"checkpoint {resumed_from} is at tick {tick}, beyond "
             f"this run's n_ticks={n_ticks}")
     hook("on_resume", tick, resumed_from)
 
-    def run_chunk(cfg, state, tick):
+    def run_chunk(end, state, tick):
+        cfg = engine.SimConfig(n_ticks=end)
         if mesh is not None:
             return engine.simulate_sharded(scenarios, program, None, data,
                                            seeds, cfg, mesh=mesh,
-                                           donate=False, init_state=state,
+                                           donate=True, init_state=state,
                                            tick0=tick)
         return engine.simulate_program(scenarios, program, None, data,
-                                       seeds, cfg, donate=False,
+                                       seeds, cfg, donate=True,
                                        init_state=state, tick0=tick)
 
     def save(state, tick):
@@ -683,22 +702,24 @@ def train_batched_durable(job: JobConfig,
     has_after_save = hooks is not None and \
         getattr(hooks, "after_save", None) is not None
     writer = ckpt_mod.AsyncCheckpointWriter() if async_save else None
+    # host copies: the rollback point (the pre-hook carry of the chunk
+    # being run) and what the async writer serializes — the device carry
+    # itself is donated to the next chunk
+    host = jax.device_get(state) if nan_guard else None
     rollbacks = 0
     try:
         res = None
         while tick < n_ticks:
-            clean_state = state          # pre-hook carry, the rollback point
             hooked = hook("before_chunk", tick, state)
             if hooked is not None:
                 state = hooked
-            step = min(save_every, n_ticks - tick)
-            cfg = engine.SimConfig(n_ticks=tick + step, snapshot_every=step)
-            res = run_chunk(cfg, state, tick)
-            # the chunk's single snapshot IS its final carry — persist it
-            # before advancing (atomic write; a kill between chunks re-runs
-            # at most this chunk)
-            new_state, new_tick = engine.snapshot_state(res, -1)
-            if nan_guard and not state_is_finite(new_state):
+            new_tick = min(tick + save_every, n_ticks)
+            res = run_chunk(new_tick, state, tick)
+            # the chunk's final carry is the checkpoint — persist it before
+            # advancing (atomic write; a kill between chunks re-runs at
+            # most this chunk)
+            state = res.final_state
+            if nan_guard and not state_is_finite(state):
                 rollbacks += 1
                 hook("on_rollback", tick,
                      f"non-finite carry after chunk ending at tick "
@@ -707,12 +728,14 @@ def train_batched_durable(job: JobConfig,
                     raise FloatingPointError(
                         f"carry still non-finite after {max_rollbacks} "
                         f"rollbacks of the chunk starting at tick {tick}")
-                state, res = clean_state, None
+                state, res = jax.device_put(host), None
                 continue
             rollbacks = 0
-            state, tick = new_state, new_tick
+            tick = new_tick
+            if nan_guard or writer is not None:
+                host = jax.device_get(state)
             hook("before_save", tick)
-            path = save(state, tick)
+            path = save(state if host is None else host, tick)
             if has_after_save:
                 if writer is not None:
                     writer.wait()        # hook must see the landed file
@@ -721,32 +744,33 @@ def train_batched_durable(job: JobConfig,
             # checkpoint already at n_ticks (or the last chunk rolled
             # back): materialize the result from the carry with a
             # zero-tick call
-            res = run_chunk(engine.SimConfig(n_ticks=n_ticks), state, tick)
+            res = run_chunk(n_ticks, state, tick)
     finally:
         if writer is not None:
             writer.close()
     return res
 
 
-def _zoo_setup(job: JobConfig, remat: str):
-    """(program factory, initial carry) for a zoo run — the two hooks that
-    turn the generic batched paths into full-zoo training."""
+def _zoo_setup(job: JobConfig):
+    """(program factory, initial-carry builder) for a zoo run — the two
+    hooks that turn the generic batched paths into full-zoo training."""
     from repro.train import zoo_program as zoo_mod
 
     cfg = job.model
 
     def program(n_batches: int) -> engine.ModelProgram:
-        return zoo_mod.make_zoo_program(cfg, job, n_batches, remat)
+        return zoo_mod.make_zoo_program(cfg, job, n_batches)
 
-    model0 = zoo_mod.init_zoo_state(cfg, job, jax.random.PRNGKey(job.seed))
-    return program, model0
+    def init_model():
+        return zoo_mod.init_zoo_state(cfg, job, jax.random.PRNGKey(job.seed))
+
+    return program, init_model
 
 
 def train_zoo(job: JobConfig,
               scenarios: Union[engine.ScenarioBatch,
                                Sequence[engine.Scenario]],
               seeds: Union[int, Sequence[int]] = 8, *,
-              remat: str = "none",
               checkpoint_path: Optional[str] = None,
               save_every: Optional[int] = None,
               **kw) -> engine.EngineResult:
@@ -759,33 +783,34 @@ def train_zoo(job: JobConfig,
     durable chunk loop, step-directory GC, async writers, NaN guard and
     chaos hooks all apply) with the model program swapped for
     `zoo_program.make_zoo_program` and the initial carry for
-    `zoo_program.init_zoo_state`. Mixed-precision configs train with bf16
+    `zoo_program.init_zoo_state`. Activations are recomputed per
+    ``job.sharding.remat``. Mixed-precision configs train with bf16
     params/activations over f32 optimizer masters; checkpoints then carry
     bf16 leaves (see `checkpoint`'s bit-view encoding) and resume
     bit-consistently. Remaining keyword arguments pass through to the
     underlying path (``n_ticks``, ``n_batches``, ``mesh``,
     ``snapshot_every``, ``keep_last``, ``nan_guard`` ...)."""
-    program, model0 = _zoo_setup(job, remat)
+    program, init_model = _zoo_setup(job)
     if checkpoint_path is not None:
         if not save_every:
             raise ValueError(
                 "train_zoo(checkpoint_path=...) needs save_every ≥ 1")
         return train_batched_durable(
             job, scenarios, seeds, checkpoint_path=checkpoint_path,
-            save_every=save_every, program=program, model0=model0, **kw)
+            save_every=save_every, program=program, init_model=init_model,
+            **kw)
     return train_batched(job, scenarios, seeds, program=program,
-                         model0=model0, **kw)
+                         init_model=init_model, **kw)
 
 
 def resume_zoo(path: str, job: JobConfig,
                scenarios: Union[engine.ScenarioBatch,
                                 Sequence[engine.Scenario]],
-               seeds: Union[int, Sequence[int]],
-               remat: str = "none"):
+               seeds: Union[int, Sequence[int]]):
     """Load a zoo run's checkpoint back into its (possibly mixed-precision)
     carry: ``(state, tick)`` for ``train_zoo(..., init_state=state,
     tick0=tick)``. The restore template is rebuilt from the job exactly as
     `train_zoo` built it, so structure drift is named, not silent."""
-    del remat                     # template depends only on the carry shape
-    _, model0 = _zoo_setup(job, "none")
-    return restore_batched(path, job, scenarios, seeds, model0=model0)
+    _, init_model = _zoo_setup(job)
+    return restore_batched(path, job, scenarios, seeds,
+                           init_model=init_model)

@@ -77,15 +77,18 @@ def init_zoo_state(cfg: ModelConfig, job: JobConfig, key):
     return {"params": params, "master": master, "opt": opt.init(master)}
 
 
-def make_zoo_step(cfg: ModelConfig, job: JobConfig, remat: str = "none"):
+def make_zoo_step(cfg: ModelConfig, job: JobConfig):
     """One zoo training iteration over the `init_zoo_state` carry:
-    ``zoo_step(model, batch, mask, j) -> (new_model, loss)``.
+    ``zoo_step(model, batch, mask, j) -> (new_model, loss)``. Activations
+    are recomputed per ``job.sharding.remat`` (default ``"full"``: at
+    published widths the saved per-layer activations would not fit one
+    chip).
 
     Shared by the engine program below and by the plain-loop side of the
     parity tests (so the bf16 pin compares the engine against an
     independent host loop over the *same* update rule, not against
     itself)."""
-    grad_step = make_loss_grad(cfg, job, remat)
+    grad_step = make_loss_grad(cfg, job, job.sharding.remat)
     opt = get_optimizer(job.optimizer, job.momentum)
     lr_fn = constant_lr(job.learning_rate)
 
@@ -114,17 +117,16 @@ def make_zoo_step(cfg: ModelConfig, job: JobConfig, remat: str = "none"):
 
 @functools.lru_cache(maxsize=32)
 def make_zoo_program(cfg: ModelConfig, job: JobConfig,
-                     n_batches: int, remat: str = "none"
-                     ) -> engine.ModelProgram:
+                     n_batches: int) -> engine.ModelProgram:
     """Any zoo ``ModelConfig`` as an engine-runnable ModelProgram.
 
     ``data`` is the `trainer.stack_batches` pytree (leading (n_batches,)
     axis), indexed ``j % n_batches`` inside the scan. The scenario ``alpha``
     is ignored — the LR comes from the job, as everywhere in the trainer.
-    Cached on the hashable (cfg, job, n_batches, remat) so repeated grids
+    Cached on the hashable (cfg, job, n_batches) so repeated grids
     share one compilation (ModelProgram hashes by identity and is a jit
     static argument)."""
-    step = make_zoo_step(cfg, job, remat)
+    step = make_zoo_step(cfg, job)
 
     def step_fn(model, data, key, mask, j, alpha):
         del key, alpha

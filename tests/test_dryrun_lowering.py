@@ -17,7 +17,8 @@ import json
 import jax
 from repro.launch import dryrun
 
-mesh = jax.make_mesh({mesh_shape}, {axes})
+mesh = jax.make_mesh({mesh_shape}, {axes},
+                     axis_types=(jax.sharding.AxisType.Auto,) * len({axes}))
 rec = dryrun.lower_one("{arch}", "{shape}", mesh=mesh, rules={rules})
 print("RESULT " + json.dumps({{
     "dominant": rec["dominant"],

@@ -18,7 +18,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.roofline.analysis import xla_cost_analysis
 from repro.roofline.hlo_cost import analyze_hlo_text
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 L, B, D = 7, 8, 128
 
 def f(x, w):

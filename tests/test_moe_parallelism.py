@@ -19,7 +19,8 @@ from repro.configs import ARCHS
 from repro.models import model_zoo
 from repro.models.common import init_params, mesh_context, DEFAULT_RULES
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 base = ARCHS["qwen2-moe-a2.7b"].reduced()
 base = base.with_(moe=dataclasses.replace(
     base.moe, num_experts=8, num_experts_unpadded=8, capacity_factor=16.0,

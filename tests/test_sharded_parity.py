@@ -234,7 +234,8 @@ def test_simulate_sharded_rejects_unknown_mesh_axes():
                             seed=0)
     sc = engine.Scenario(price=engine.PriceSpec.uniform(0.2, 1.0),
                          alpha=0.1, bid_schedule=np.tile([0.9], (4, 1)))
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     with pytest.raises(ValueError, match="data"):
         engine.simulate_sharded(
             engine.stack_scenarios([sc]),
