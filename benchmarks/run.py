@@ -1,5 +1,6 @@
-"""Benchmark harness — one function per paper table/figure, plus roofline
-and step-microbenchmarks. Prints ``name,us_per_call,derived`` CSV rows.
+"""Benchmark harness — one function per paper table/figure, plus the
+engine, trainer, service and supervisor paths. Prints
+``name,us_per_call,derived`` CSV rows.
 
   fig3  — strategies under synthetic i.i.d. prices (uniform & Gaussian):
           cost to reach the target error, mean ± 95% CI over 8 seeds on the
@@ -29,11 +30,6 @@ and step-microbenchmarks. Prints ``name,us_per_call,derived`` CSV rows.
           durable run unfailed vs under a seeded kill+corrupt fault plan
           (restarts, ticks lost, MTTR, wall overhead %).
           Its workers need the device, so it runs alone (--only chaos).
-  roofline — per (arch × shape) dominant roofline term from the dry-run
-          JSON (results/dryrun_singlepod.json), if present.
-  steps — wall-time microbenchmarks of the elastic train/serve steps on
-          reduced configs (CPU).
-  kernels — interpret-mode kernel timings vs jnp oracle (CPU).
 
 Run: PYTHONPATH=src python -m benchmarks.run [--only fig3,fig4] [--smoke]
 
@@ -627,99 +623,6 @@ def bench_multibid():
         emit("multibid_curve", 0.0, curve)
 
 
-def bench_roofline():
-    path = os.path.join(os.path.dirname(__file__), "..", "results",
-                        "dryrun_singlepod.json")
-    if not os.path.exists(path):
-        emit("roofline_missing", 0.0,
-             "run: python -m repro.launch.dryrun --all --out "
-             "results/dryrun_singlepod")
-        return
-    with open(path) as f:
-        data = json.load(f)
-    for rec in data["results"]:
-        emit(f"roofline_{rec['arch']}_{rec['shape']}",
-             float(rec.get("compile_s", 0)) * 1e6,
-             f"dominant={rec['dominant']};"
-             f"t_comp={rec['t_compute_s']:.3e};"
-             f"t_mem={rec['t_memory_s']:.3e};"
-             f"t_coll={rec['t_collective_s']:.3e};"
-             f"useful_flops={rec['useful_flops_ratio']:.2f}")
-
-
-def bench_steps():
-    import jax
-    import jax.numpy as jnp
-
-    from repro.configs import ARCHS
-    from repro.configs.base import InputShape, JobConfig
-    from repro.data.synthetic import lm_batch
-    from repro.models import model_zoo
-    from repro.models.common import init_params
-    from repro.train.train_step import (init_train_state, make_serve_step,
-                                        make_train_step)
-
-    archs = ["deepseek-7b", "qwen2-moe-a2.7b", "mamba2-1.3b"]
-    for arch in archs[:1] if SMOKE else archs:
-        cfg = ARCHS[arch].reduced()
-        job = JobConfig(model=cfg, shape=InputShape("t", 64, 8, "train"),
-                        n_workers=4)
-        step = jax.jit(make_train_step(cfg, job, remat="none"))
-        params, opt = init_train_state(cfg, job, jax.random.PRNGKey(0))
-        batch = {k: jnp.asarray(v) for k, v in lm_batch(cfg, 8, 64,
-                                                        0).items()}
-        mask = jnp.ones(4)
-        out = step(params, opt, batch, mask, jnp.int32(0))
-        jax.block_until_ready(out[2]["loss"])
-        t0 = time.time()
-        reps = 1 if SMOKE else 5
-        for i in range(reps):
-            out = step(out[0], out[1], batch, mask, jnp.int32(i))
-        jax.block_until_ready(out[2]["loss"])
-        emit(f"steps_train_{arch}", (time.time() - t0) * 1e6 / reps,
-             f"loss={float(out[2]['loss']):.3f}")
-
-        serve = jax.jit(make_serve_step(cfg))
-        caches = init_params(model_zoo.cache_defs(cfg, 8, 64),
-                             jax.random.PRNGKey(1), jnp.float32)
-        tok = jnp.zeros((8, 1), jnp.int32)
-        nxt, caches = serve(params, caches, tok, jnp.int32(0))
-        jax.block_until_ready(nxt)
-        t0 = time.time()
-        for i in range(reps):
-            nxt, caches = serve(params, caches, nxt, jnp.int32(i + 1))
-        jax.block_until_ready(nxt)
-        emit(f"steps_serve_{arch}", (time.time() - t0) * 1e6 / reps,
-             "decode_1tok")
-
-
-def bench_kernels():
-    import jax
-
-    from repro.kernels import ops, ref
-
-    key = jax.random.PRNGKey(0)
-    q = jax.random.normal(key, (1, 256, 4, 64))
-    k = jax.random.normal(jax.random.fold_in(key, 1), (1, 256, 2, 64))
-    v = jax.random.normal(jax.random.fold_in(key, 2), (1, 256, 2, 64))
-    for name, fn in [
-        ("kernel_flash_interpret",
-         lambda: ops.flash_mha(q, k, v, causal=True, interpret=True)),
-        ("kernel_flash_ref",
-         lambda: ref.mha_reference(q.transpose(0, 2, 1, 3),
-                                   k.transpose(0, 2, 1, 3),
-                                   v.transpose(0, 2, 1, 3), causal=True)),
-    ]:
-        out = fn()
-        jax.block_until_ready(out)
-        reps = 1 if SMOKE else 3
-        t0 = time.time()
-        for _ in range(reps):
-            jax.block_until_ready(fn())
-        emit(name, (time.time() - t0) * 1e6 / reps,
-             "interpret-mode-CPU" if "interpret" in name else "jnp-oracle")
-
-
 # --------------------------------------------------------------------------
 # sharded engine scaling across virtual devices
 # --------------------------------------------------------------------------
@@ -963,9 +866,6 @@ BENCHES = {
     "serve": bench_serve,
     "multibid": bench_multibid,
     "zoo": bench_zoo,
-    "roofline": bench_roofline,
-    "steps": bench_steps,
-    "kernels": bench_kernels,
     "chaos": bench_chaos,
 }
 

@@ -8,6 +8,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models import attention as attn
 from repro.models import mla as mla_mod
 from repro.models import moe as moe_mod
@@ -130,10 +131,12 @@ def embed_tokens(params, cfg, tokens):
 
 
 def unembed(params, cfg, x):
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = x @ head.astype(x.dtype)
-    return shard(logits, "batch", None, "tp")
+    with obs.scope("repro.loss_head"):
+        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = x @ head.astype(x.dtype)
+        return shard(logits, "batch", None, "tp")
 
 
 def lm_forward(params, cfg, tokens, *, prefix_embeds=None, remat="full"

@@ -65,6 +65,7 @@ from jax import lax
 from jax.scipy.special import ndtr, ndtri
 from jax.sharding import NamedSharding, PartitionSpec
 
+from repro import obs
 # The pad value for absent workers in stacked bid schedules lives with the
 # strategies (which build the schedules); re-exported here for engine users.
 from repro.core.strategies import NEVER_BID
@@ -784,6 +785,7 @@ class TickMarket(NamedTuple):
     k_grad: jnp.ndarray          # the model step's PRNG key
 
 
+@obs.scope("repro.market")
 def _market_tick(sc: ScenarioBatch, base, seed, t, j, bucket0,
                  k) -> TickMarket:
     """Market/accounting logic for one (scenario, seed) cell at absolute
@@ -836,9 +838,10 @@ def _gate_model(running, stepped, old):
     leaf — easy to do in a mixed-precision update — would otherwise change
     the scan carry's pytree dtypes mid-scan and fail to converge in
     ``lax.scan``'s fixed-point check."""
-    return jax.tree.map(
-        lambda new, o: jnp.where(running, new.astype(o.dtype), o),
-        stepped, old)
+    with obs.scope("repro.gate"):
+        return jax.tree.map(
+            lambda new, o: jnp.where(running, new.astype(o.dtype), o),
+            stepped, old)
 
 
 def _sim_one(sc: ScenarioBatch, state0: SimState, data, seed,
@@ -863,29 +866,32 @@ def _sim_one(sc: ScenarioBatch, state0: SimState, data, seed,
 
         # one model iteration; the update only lands when the iteration
         # actually ran — idle/finished ticks are true no-ops on every leaf
-        stepped, metric = program.step_fn(
-            state.model, data, m.k_grad, m.mask.astype(jnp.float32),
-            state.j, sc.alpha)
+        with obs.scope("repro.step"):
+            stepped, metric = program.step_fn(
+                state.model, data, m.k_grad, m.mask.astype(jnp.float32),
+                state.j, sc.alpha)
         model = _gate_model(m.running, stepped, state.model)
-        metric = jnp.asarray(metric).astype(jnp.float32)
 
-        t_new = state.t + m.dt
-        cost_new = state.total_cost + m.cost_inc
-        idle_new = state.total_idle + m.idle_inc
+        with obs.scope("repro.record"):
+            metric = jnp.asarray(metric).astype(jnp.float32)
+            t_new = state.t + m.dt
+            cost_new = state.total_cost + m.cost_inc
+            idle_new = state.total_idle + m.idle_inc
 
-        idx = jnp.minimum(state.j, j_max - 1)
+            idx = jnp.minimum(state.j, j_max - 1)
 
-        def put(traj, val):
-            return traj.at[idx].set(jnp.where(m.running, val, traj[idx]))
+            def put(traj, val):
+                return traj.at[idx].set(jnp.where(m.running, val,
+                                                  traj[idx]))
 
-        new = SimState(
-            t=t_new, j=state.j + m.running.astype(jnp.int32),
-            bucket=m.bucket,
-            total_cost=cost_new, total_idle=idle_new, model=model,
-            err_traj=put(state.err_traj, metric),
-            cost_traj=put(state.cost_traj, cost_new),
-            time_traj=put(state.time_traj, t_new),
-            y_traj=put(state.y_traj, m.y))
+            new = SimState(
+                t=t_new, j=state.j + m.running.astype(jnp.int32),
+                bucket=m.bucket,
+                total_cost=cost_new, total_idle=idle_new, model=model,
+                err_traj=put(state.err_traj, metric),
+                cost_traj=put(state.cost_traj, cost_new),
+                time_traj=put(state.time_traj, t_new),
+                y_traj=put(state.y_traj, m.y))
         return new, None
 
     def run(state, ks):
@@ -938,31 +944,34 @@ def _sim_blocked(batch: ScenarioBatch, state0: SimState, data, seeds,
                         state.bucket, k)
         # blocked steps gate on `running` internally (element-for-element
         # in the fused update) — no engine-side tree.map(where) pass
-        model, metric = program.step_fn(
-            state.model, data, m.k_grad, m.mask.astype(jnp.float32),
-            state.j, alpha2, m.running)
-        # blocked steps own the model gating, but the trajectory contract
-        # is engine-owned either way: metrics land in f32 buffers
-        metric = jnp.asarray(metric).astype(jnp.float32)
+        with obs.scope("repro.step"):
+            model, metric = program.step_fn(
+                state.model, data, m.k_grad, m.mask.astype(jnp.float32),
+                state.j, alpha2, m.running)
 
-        t_new = state.t + m.dt
-        cost_new = state.total_cost + m.cost_inc
-        idle_new = state.total_idle + m.idle_inc
+        with obs.scope("repro.record"):
+            # blocked steps own the model gating, but the trajectory
+            # contract is engine-owned either way: metrics land in f32
+            # buffers
+            metric = jnp.asarray(metric).astype(jnp.float32)
+            t_new = state.t + m.dt
+            cost_new = state.total_cost + m.cost_inc
+            idle_new = state.total_idle + m.idle_inc
 
-        idx = jnp.minimum(state.j, j_max - 1)
+            idx = jnp.minimum(state.j, j_max - 1)
 
-        def put(traj, val):
-            return traj.at[si, ri, idx].set(
-                jnp.where(m.running, val, traj[si, ri, idx]))
+            def put(traj, val):
+                return traj.at[si, ri, idx].set(
+                    jnp.where(m.running, val, traj[si, ri, idx]))
 
-        new = SimState(
-            t=t_new, j=state.j + m.running.astype(jnp.int32),
-            bucket=m.bucket,
-            total_cost=cost_new, total_idle=idle_new, model=model,
-            err_traj=put(state.err_traj, metric),
-            cost_traj=put(state.cost_traj, cost_new),
-            time_traj=put(state.time_traj, t_new),
-            y_traj=put(state.y_traj, m.y))
+            new = SimState(
+                t=t_new, j=state.j + m.running.astype(jnp.int32),
+                bucket=m.bucket,
+                total_cost=cost_new, total_idle=idle_new, model=model,
+                err_traj=put(state.err_traj, metric),
+                cost_traj=put(state.cost_traj, cost_new),
+                time_traj=put(state.time_traj, t_new),
+                y_traj=put(state.y_traj, m.y))
         return new, None
 
     def run(state, ks):
@@ -1061,13 +1070,20 @@ def simulate_program(scenarios, program: ModelProgram, model0, data, seeds,
     tick0 = int(tick0)
     n_run = _check_run_window(cfg, tick0)
     if init_state is None:
-        init_state = initial_state(scenarios, model0, len(seeds))
+        j0 = np.zeros((scenarios.n_scenarios, len(seeds)), np.int32)
+        with obs.span("repro.engine.init"):
+            init_state = initial_state(scenarios, model0, len(seeds))
+    else:
+        # read before the call donates it; a carry that a previous call
+        # returned holds it fetched already, so nothing is copied
+        j0 = np.asarray(init_state.j)
     del model0
     fn = _simulate_jit_donated if donate else _simulate_jit
-    final, snaps = fn(scenarios, init_state, data, seeds,
-                      jnp.asarray(tick0, jnp.int32), program, n_run,
-                      cfg.snapshot_every)
-    return _engine_result(final, snaps, scenarios, cfg, tick0, n_run)
+    with obs.span("repro.engine.dispatch"):
+        final, snaps = fn(scenarios, init_state, data, seeds,
+                          jnp.asarray(tick0, jnp.int32), program, n_run,
+                          cfg.snapshot_every)
+    return _engine_result(final, snaps, scenarios, cfg, tick0, n_run, j0)
 
 
 def _check_run_window(cfg: SimConfig, tick0: int) -> int:
@@ -1089,24 +1105,44 @@ def _check_run_window(cfg: SimConfig, tick0: int) -> int:
 
 
 def _engine_result(final: SimState, snaps, scenarios: ScenarioBatch,
-                   cfg: SimConfig, tick0: int, n_run: int) -> EngineResult:
+                   cfg: SimConfig, tick0: int, n_run: int,
+                   j0: np.ndarray) -> EngineResult:
     snap_ticks = None
     if snaps is not None:
         n_snap = n_run // cfg.snapshot_every
         snap_ticks = tick0 + cfg.snapshot_every * np.arange(1, n_snap + 1)
-    return EngineResult(
-        errors=np.asarray(final.err_traj),
-        costs=np.asarray(final.cost_traj),
-        times=np.asarray(final.time_traj),
-        ys=np.asarray(final.y_traj),
-        iterations=np.asarray(final.j),
-        total_time=np.asarray(final.t),
-        total_cost=np.asarray(final.total_cost),
-        total_idle=np.asarray(final.total_idle),
-        J=np.asarray(scenarios.J),
-        final_state=final,
-        snapshots=snaps,
-        snapshot_ticks=snap_ticks)
+    with obs.span("repro.engine.fetch"):
+        res = EngineResult(
+            errors=np.asarray(final.err_traj),
+            costs=np.asarray(final.cost_traj),
+            times=np.asarray(final.time_traj),
+            ys=np.asarray(final.y_traj),
+            iterations=np.asarray(final.j),
+            total_time=np.asarray(final.t),
+            total_cost=np.asarray(final.total_cost),
+            total_idle=np.asarray(final.total_idle),
+            J=np.asarray(scenarios.J),
+            final_state=final,
+            snapshots=snaps,
+            snapshot_ticks=snap_ticks)
+    _count_ticks(res, j0, n_run, scenarios.n_max)
+    return res
+
+
+def _count_ticks(res: EngineResult, j0: np.ndarray, n_run: int,
+                 n_max: int) -> None:
+    """Add this call's ticks, running ticks, shard slots and active shards
+    (`obs`) from the fetched trajectories: iterations ``j0`` to
+    ``res.iterations`` of each replica ran in it."""
+    j1 = res.iterations
+    ticks = j1.size * n_run
+    at = np.arange(res.ys.shape[-1])
+    ran = (at >= j0[..., None]) & (at < j1[..., None])
+    obs.count("engine.ticks", ticks)
+    obs.count("engine.running_ticks", int(np.sum(j1 - j0)))
+    obs.count("engine.shard_slots", ticks * n_max)
+    obs.count("engine.active_shards",
+              int(round(float(np.sum(np.where(ran, res.ys, 0.0))))))
 
 
 # --------------------------------------------------------------------------
@@ -1244,23 +1280,27 @@ def simulate_sharded(scenarios, program: ModelProgram, model0, data, seeds,
                jax.tree.map(lambda x: _pad_axis(x, 0, s_pad), scenarios))
     seeds_p = _pad_axis(seeds, 0, r_pad)
     if init_state is None:
-        state0 = initial_state(batch_p, model0, r_pad,
-                               sharding=NamedSharding(mesh,
-                                                      _grid_specs(mesh)[1]))
+        j0 = np.zeros((S, R), np.int32)
+        with obs.span("repro.engine.init"):
+            state0 = initial_state(
+                batch_p, model0, r_pad,
+                sharding=NamedSharding(mesh, _grid_specs(mesh)[1]))
     else:
+        j0 = np.asarray(init_state.j)   # as in `simulate_program`
         state0 = jax.tree.map(
             lambda x: _pad_axis(_pad_axis(x, 0, s_pad), 1, r_pad),
             init_state)
     del model0
     fn = _simulate_sharded_jit_donated if donate else _simulate_sharded_jit
-    final, snaps = fn(batch_p, state0, data, seeds_p,
-                      jnp.asarray(tick0, jnp.int32), mesh, program, n_run,
-                      cfg.snapshot_every)
+    with obs.span("repro.engine.dispatch"):
+        final, snaps = fn(batch_p, state0, data, seeds_p,
+                          jnp.asarray(tick0, jnp.int32), mesh, program,
+                          n_run, cfg.snapshot_every)
     if (s_pad, r_pad) != (S, R):
         final = jax.tree.map(lambda x: x[:S, :R], final)
         if snaps is not None:
             snaps = jax.tree.map(lambda x: x[:S, :R], snaps)
-    return _engine_result(final, snaps, scenarios, cfg, tick0, n_run)
+    return _engine_result(final, snaps, scenarios, cfg, tick0, n_run, j0)
 
 
 def snapshot_state(result: EngineResult, index: int = -1):

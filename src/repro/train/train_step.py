@@ -7,6 +7,7 @@ from typing import Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import JobConfig, ModelConfig
 from repro.models import model_zoo
 from repro.models.common import shard
@@ -31,19 +32,22 @@ def make_loss_grad(cfg: ModelConfig, job: JobConfig, remat: str = "full"):
         sum-form so microbatch accumulation is exactly the full-batch
         masked mean of Eq. (5)."""
         logits, aux = model_zoo.forward(p, cfg, batch, remat=remat)
-        if cfg.family == "vlm":
-            logits_txt = logits[:, cfg.vision.num_patches:]
-        else:
-            logits_txt = logits
-        labels = batch["labels"]
-        s = labels.shape[1]
-        w = elastic_token_weights(active_mask, b, s, batch.get("label_mask"))
-        w = shard(w, "batch", None)
-        lse = jax.nn.logsumexp(logits_txt.astype(jnp.float32), axis=-1)
-        gold = jnp.take_along_axis(logits_txt.astype(jnp.float32),
-                                   labels[..., None], axis=-1)[..., 0]
-        nll_sum = ((lse - gold) * w.astype(jnp.float32)).sum()
-        return nll_sum, w.astype(jnp.float32).sum(), aux
+        with obs.scope("repro.loss_head"):
+            if cfg.family == "vlm":
+                logits_txt = logits[:, cfg.vision.num_patches:]
+            else:
+                logits_txt = logits
+            labels = batch["labels"]
+            s = labels.shape[1]
+            w = elastic_token_weights(active_mask, b, s,
+                                      batch.get("label_mask"))
+            w = shard(w, "batch", None)
+            lse = jax.nn.logsumexp(logits_txt.astype(jnp.float32), axis=-1)
+            gold = jnp.take_along_axis(logits_txt.astype(jnp.float32),
+                                       labels[..., None], axis=-1)[..., 0]
+            nll_sum = ((lse - gold) * w.astype(jnp.float32)).sum()
+            w_sum = w.astype(jnp.float32).sum()
+        return nll_sum, w_sum, aux
 
     def grad_step(params, batch: Dict, active_mask):
         tokens = batch["tokens"]

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import JobConfig
 from repro.core.strategies import Strategy
 from repro.data.synthetic import lm_batch
@@ -445,7 +446,8 @@ def _prepare_batched(job: JobConfig, scenarios, *, n_ticks, n_batches,
     stream (this is how `train_zoo` plugs `zoo_program.make_zoo_program`
     in). Pass a callable ``n_batches -> ModelProgram``."""
     if not isinstance(scenarios, engine.ScenarioBatch):
-        scenarios = engine.stack_scenarios(scenarios)
+        with obs.span("repro.prepare.scenarios"):
+            scenarios = engine.stack_scenarios(scenarios)
     if scenarios.n_max != job.n_workers:
         raise ValueError(
             f"scenario fleet width {scenarios.n_max} != job.n_workers "
@@ -453,7 +455,9 @@ def _prepare_batched(job: JobConfig, scenarios, *, n_ticks, n_batches,
             "slice")
     j_max = scenarios.j_max
     n_batches = n_batches or j_max
-    data = stack_batches(job, n_batches, seed=batch_seed, batch_fn=batch_fn)
+    with obs.span("repro.prepare.batches"):
+        data = stack_batches(job, n_batches, seed=batch_seed,
+                             batch_fn=batch_fn)
     if program is not None:
         program = program(n_batches)
     elif megabatch:

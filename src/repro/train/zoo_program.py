@@ -40,6 +40,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import JobConfig, ModelConfig
 from repro.models import model_zoo
 from repro.models.common import abstract_params, init_params
@@ -96,20 +97,22 @@ def make_zoo_step(cfg: ModelConfig, job: JobConfig):
         def zoo_step(model, batch, mask, j):
             params, opt_state = model
             grads, loss, _ = grad_step(params, batch, mask)
-            new_params, new_opt = opt.update(grads, opt_state, params,
-                                             lr_fn(j))
+            with obs.scope("repro.update"):
+                new_params, new_opt = opt.update(grads, opt_state, params,
+                                                 lr_fn(j))
             return (new_params, new_opt), loss
 
         return zoo_step
 
     def zoo_step(model, batch, mask, j):
         grads, loss, _ = grad_step(model["params"], batch, mask)
-        g32 = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-        master, opt_state = opt.update(g32, model["opt"], model["master"],
-                                       lr_fn(j))
-        # refresh the low-precision working copy from the masters
-        params = jax.tree.map(lambda m, p: m.astype(p.dtype), master,
-                              model["params"])
+        with obs.scope("repro.update"):
+            g32 = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
+            master, opt_state = opt.update(g32, model["opt"],
+                                           model["master"], lr_fn(j))
+            # refresh the low-precision working copy from the masters
+            params = jax.tree.map(lambda m, p: m.astype(p.dtype), master,
+                                  model["params"])
         return {"params": params, "master": master, "opt": opt_state}, loss
 
     return zoo_step
