@@ -339,14 +339,22 @@ def phase_zoo(cfg: SmokeConfig) -> Dict:
 
 def phase_zoo_mesh(cfg: SmokeConfig) -> Dict:
     """One full-width model per chip over a 4-scenario mesh, against each
-    scenario run alone on one chip. Equal bits pass; so do losses within
-    float32 rounding (relative 1e-5), reported with the difference found,
-    since a sharded program may order a reduction differently."""
+    scenario run on one chip in the layout a chip of the mesh holds it:
+    alone, as one cell. (The CPU pads each device of a mesh to two cells,
+    `engine._padded_size`, so there each scenario runs beside a copy of
+    itself: one cell is computed on per-worker slices, a wider grid on
+    the whole batch.) Equal bits pass; so do losses within float32
+    rounding (relative 1e-5), reported with the difference found, since a
+    sharded program may order a reduction differently."""
+    import jax
+
     from repro.launch.mesh import make_scenario_mesh
+    from repro.sim import engine
     from repro.train.trainer import train_zoo
 
     k = cfg.mesh_chips
     job, scenarios, seeds = cfg.workload(n_bid_rows=k)
+    per_chip = engine._padded_size(k, k, jax.devices()[0].platform) // k
     res = train_zoo(job, scenarios, seeds, n_ticks=cfg.zoo_ticks,
                     mesh=make_scenario_mesh(k))
     losses = res.losses
@@ -356,7 +364,8 @@ def phase_zoo_mesh(cfg: SmokeConfig) -> Dict:
            f"losses not finite or not falling: {losses}")
     bits_equal, worst = True, 0.0
     for i in range(k):
-        alone = train_zoo(job, [scenarios[i]], seeds, n_ticks=cfg.zoo_ticks)
+        alone = train_zoo(job, [scenarios[i]] * per_chip, seeds,
+                          n_ticks=cfg.zoo_ticks)
         _check(np.array_equal(np.isnan(alone.losses[0]),
                               np.isnan(losses[i])),
                f"scenario {i} ran other iterations alone than on the mesh")

@@ -32,7 +32,9 @@ one in its ``op_name``):
   gold logit, token weights) — ``loss_head_share``;
 - ``repro.update``: the optimizer update and the casts of the mixed
   precision carry — ``update_share``;
-- ``repro.gate``: `engine._gate_model` — ``update_share``.
+- ``repro.gate``: the tick's ``lax.cond`` on ``running`` around
+  ``repro.step`` (in a vmapped grid a select over the model carry) —
+  ``update_share``.
 
 Counters, added after each engine fetch over the run's (scenario, seed)
 replicas:
@@ -40,10 +42,14 @@ replicas:
 - ``engine.ticks``: ticks run;
 - ``engine.running_ticks``: ticks on which the iteration ran —
   ``idle_tick_share`` is one less their ratio;
-- ``engine.shard_slots``: ticks × the fleet width: the model step trains
-  every worker's shard on every tick, running or not;
+- ``engine.shard_slots``: ticks × the fleet width;
 - ``engine.active_shards``: Σ of the active workers over the iterations
-  that ran — ``useful_shard_share`` is their ratio to the slots.
+  that ran — ``useful_shard_share`` is their ratio to the slots;
+- ``engine.skipped_shards``: shard slots whose forward and backward did
+  not run: 0 on a vmapped grid, which computes every worker's shard on
+  every tick; on a one-cell run, which branches, the slots of idle and
+  finished ticks, and with the program's ``cell_step_fn`` (the zoo
+  program's) every slot but the active shards.
 """
 from __future__ import annotations
 

@@ -28,8 +28,13 @@ diverge (the fig4 regime; ``from_trace_ticks`` keeps the legacy per-tick
 consumption for tick-exact parity pins). A scenario stops accumulating once
 it has completed its ``J`` iterations. Active workers pay the *price*, not
 the bid (§IV). Iterations with zero active workers are a *true no-op*: the
-whole model pytree is gated on ``running`` with ``jnp.where``, so
-idle/finished ticks cannot leak scaled gradients into the iterate.
+step runs under ``lax.cond(running, ...)`` (under the grid's ``vmap`` a
+select over the whole model pytree), so idle/finished ticks cannot leak
+scaled gradients into the iterate. A grid of one scenario × one seed is not
+vmapped: there the ``cond`` is a branch, so idle and finished ticks compute
+no step, and the tick runs the program's ``cell_step_fn`` where there is
+one, which may branch per worker (`train.zoo_program`'s computes only the
+active workers' shards).
 
 Checkpointing is scan-native: ``SimConfig.snapshot_every = k`` restructures
 the scan into k-tick chunks whose per-chunk output is the *entire* carry
@@ -519,18 +524,25 @@ class ModelProgram:
     recorded in the per-iteration trajectory (error for the quadratic
     oracle, batch loss for real models).
 
-    The engine gates the returned model on the iteration actually running
-    (``jnp.where`` over every leaf), so the step need not handle the
-    all-preempted / finished cases — idle ticks are true no-ops. Gating is
-    *dtype-agnostic*: each gated leaf is cast back to the carry leaf's
-    dtype (`_gate_model`), so mixed-precision models — bf16 params beside
-    f32 optimizer masters, as `train.zoo_program` builds — cannot promote
-    the scan carry even if a step leaks a weak f32 or promoted leaf; and
+    The engine runs the step under ``lax.cond`` on the iteration actually
+    running (under the grid's ``vmap`` a select over every leaf), so the
+    step need not handle the all-preempted / finished cases — idle ticks
+    are true no-ops. ``mask`` is already zero on ticks that do not run.
+    Gating is *dtype-agnostic*: each stepped leaf is cast back to the carry
+    leaf's dtype (`_cast_like`), so mixed-precision models — bf16 params
+    beside f32 optimizer masters, as `train.zoo_program` builds — cannot
+    promote the scan carry even if a step leaks a weak f32 or promoted
+    leaf; and
     ``metric`` is cast to f32 before it lands in the trajectory, so steps
     may return a bf16 loss. ``data`` is an arbitrary pytree threaded
     unbatched through both scan layouts (closed over in the vmapped path,
     a replicated `PartitionSpec()` prefix in the sharded path) — per-
     program batch streams ride along without engine changes.
+
+    ``cell_step_fn``, where given, has ``step_fn``'s signature and result
+    and replaces it when the grid is one scenario × one seed (`_one_cell`),
+    which the engine runs unvmapped: there a ``lax.cond`` inside the step
+    is a real branch, where under ``vmap`` it would compute both sides.
 
     ``blocked=True`` selects the megabatched scan layout instead: the tick
     scan runs *outside* the grid vmap, the market logic is vmapped per
@@ -556,6 +568,7 @@ class ModelProgram:
     step_fn: Callable[..., Any]
     name: str = "program"
     blocked: bool = False
+    cell_step_fn: Optional[Callable[..., Any]] = None
 
 
 @functools.lru_cache(maxsize=None)
@@ -832,48 +845,60 @@ def _market_tick(sc: ScenarioBatch, base, seed, t, j, bucket0,
                       dt=dt, k_grad=k_grad)
 
 
-def _gate_model(running, stepped, old):
-    """Land the stepped model only on running ticks, per leaf, preserving
-    each carry leaf's dtype: a step that returns a promoted (or weak-f32)
-    leaf — easy to do in a mixed-precision update — would otherwise change
-    the scan carry's pytree dtypes mid-scan and fail to converge in
-    ``lax.scan``'s fixed-point check."""
-    with obs.scope("repro.gate"):
-        return jax.tree.map(
-            lambda new, o: jnp.where(running, new.astype(o.dtype), o),
-            stepped, old)
+def _cast_like(stepped, old):
+    """``(model, metric)`` with each model leaf cast to ``old``'s dtype: a
+    step that returns a promoted (or weak-f32) leaf — easy to do in a
+    mixed-precision update — would otherwise change the scan carry's pytree
+    dtypes mid-scan, and the tick's ``cond`` branches would disagree."""
+    model, metric = stepped
+    return jax.tree.map(lambda n, o: n.astype(o.dtype), model, old), metric
 
 
 def _sim_one(sc: ScenarioBatch, state0: SimState, data, seed,
-             program: ModelProgram, n_run: int, k_snap: int, tick0):
-    """Simulate one scenario × one seed (vmapped twice by `simulate`),
-    running ``n_run`` ticks from carry ``state0`` at absolute tick ``tick0``
-    (0 for a fresh run; a restored checkpoint resumes mid-trace — per-tick
-    RNG keys are folded from the absolute tick index, so the continuation
-    is bit-exact). ``tick0`` is *traced* (data, not a static shape), so
+             step_fn, n_run: int, k_snap: int, tick0):
+    """Simulate one scenario × one seed (vmapped twice by `simulate`, or
+    run alone when the grid is one cell: `_one_cell`), running ``n_run``
+    ticks from carry ``state0`` at absolute tick ``tick0`` (0 for a fresh
+    run; a restored checkpoint resumes mid-trace — per-tick RNG keys are
+    folded from the absolute tick index, so the continuation is
+    bit-exact). ``tick0`` is *traced* (data, not a static shape), so
     host-chunked drivers replaying uniform ``n_run`` windows share one
     compiled program. ``sc`` holds per-scenario scalars/rows (leading S
     axis stripped). Returns ``(final_state, snapshots)``: with
     ``k_snap > 0`` the scan runs in k-tick chunks and stacks the full carry
     after each chunk (the checkpoint stream); otherwise snapshots is
-    None."""
+    None.
+
+    Each tick runs the program's ``step_fn`` under ``lax.cond(running,
+    ...)``, whose other branch returns the carry as it was, and passes it
+    the effective mask ``mask & running``. Run alone the step is skipped
+    on idle and finished ticks; vmapped, the batched ``cond`` is a select
+    that computes the step on every tick and keeps the old carry where
+    the iteration did not run."""
     j_max = sc.bid_table.shape[1]
     base = jax.random.fold_in(jax.random.PRNGKey(20), seed)
     assert_carry_dtypes(state0)
+
+    def step(model, m: TickMarket, j):
+        # the effective mask: a finished tick's workers compute nothing
+        mask = (m.mask & m.running).astype(jnp.float32)
+        with obs.scope("repro.step"):
+            stepped, metric = step_fn(model, data, m.k_grad, mask, j,
+                                      sc.alpha)
+        metric = jnp.asarray(metric).astype(jnp.float32)
+        return _cast_like((stepped, metric), model)
 
     def tick(state: SimState, k):
         m = _market_tick(sc, base, seed, state.t, state.j, state.bucket, k)
 
         # one model iteration; the update only lands when the iteration
         # actually ran — idle/finished ticks are true no-ops on every leaf
-        with obs.scope("repro.step"):
-            stepped, metric = program.step_fn(
-                state.model, data, m.k_grad, m.mask.astype(jnp.float32),
-                state.j, sc.alpha)
-        model = _gate_model(m.running, stepped, state.model)
+        with obs.scope("repro.gate"):
+            model, metric = lax.cond(
+                m.running, lambda old: step(old, m, state.j),
+                lambda old: (old, jnp.zeros((), jnp.float32)), state.model)
 
         with obs.scope("repro.record"):
-            metric = jnp.asarray(metric).astype(jnp.float32)
             t_new = state.t + m.dt
             cost_new = state.total_cost + m.cost_inc
             idle_new = state.total_idle + m.idle_inc
@@ -997,14 +1022,29 @@ def _sim_blocked(batch: ScenarioBatch, state0: SimState, data, seeds,
     return run(state0, ticks), None
 
 
+def _one_cell(program: ModelProgram, grid) -> bool:
+    """True when a (local) ``(S, R)`` grid runs unvmapped, so that its tick
+    and the program's ``cell_step_fn`` can branch: one scenario × one seed
+    of a program that is not blocked."""
+    return not program.blocked and tuple(grid) == (1, 1)
+
+
 def _vmapped_sim(batch: ScenarioBatch, state0, data, seeds, tick0,
                  program: ModelProgram, n_run: int, k_snap: int):
     if program.blocked:
         return _sim_blocked(batch, state0, data, seeds, tick0, program,
                             n_run, k_snap)
+    if _one_cell(program, state0.t.shape):
+        # one cell: squeeze the (1, 1) grid axes off and put them back
+        final, snaps = _sim_one(
+            jax.tree.map(lambda x: x[0], batch),
+            jax.tree.map(lambda x: x[0, 0], state0), data, seeds[0],
+            program.cell_step_fn or program.step_fn, n_run, k_snap, tick0)
+        return jax.tree.map(lambda x: x[None, None], (final, snaps))
 
     def one(sc, st, seed, t0):
-        return _sim_one(sc, st, data, seed, program, n_run, k_snap, t0)
+        return _sim_one(sc, st, data, seed, program.step_fn, n_run, k_snap,
+                        t0)
 
     over_seeds = jax.vmap(one, in_axes=(None, 0, 0, None))
     over_scenarios = jax.vmap(over_seeds, in_axes=(0, 0, None, None))
@@ -1083,7 +1123,8 @@ def simulate_program(scenarios, program: ModelProgram, model0, data, seeds,
         final, snaps = fn(scenarios, init_state, data, seeds,
                           jnp.asarray(tick0, jnp.int32), program, n_run,
                           cfg.snapshot_every)
-    return _engine_result(final, snaps, scenarios, cfg, tick0, n_run, j0)
+    return _engine_result(final, snaps, scenarios, cfg, tick0, n_run, j0,
+                          program, _one_cell(program, j0.shape))
 
 
 def _check_run_window(cfg: SimConfig, tick0: int) -> int:
@@ -1106,7 +1147,8 @@ def _check_run_window(cfg: SimConfig, tick0: int) -> int:
 
 def _engine_result(final: SimState, snaps, scenarios: ScenarioBatch,
                    cfg: SimConfig, tick0: int, n_run: int,
-                   j0: np.ndarray) -> EngineResult:
+                   j0: np.ndarray, program: ModelProgram,
+                   one_cell: bool) -> EngineResult:
     snap_ticks = None
     if snaps is not None:
         n_snap = n_run // cfg.snapshot_every
@@ -1125,24 +1167,32 @@ def _engine_result(final: SimState, snaps, scenarios: ScenarioBatch,
             final_state=final,
             snapshots=snaps,
             snapshot_ticks=snap_ticks)
-    _count_ticks(res, j0, n_run, scenarios.n_max)
+    _count_ticks(res, j0, n_run, scenarios.n_max, program, one_cell)
     return res
 
 
 def _count_ticks(res: EngineResult, j0: np.ndarray, n_run: int,
-                 n_max: int) -> None:
-    """Add this call's ticks, running ticks, shard slots and active shards
-    (`obs`) from the fetched trajectories: iterations ``j0`` to
-    ``res.iterations`` of each replica ran in it."""
+                 n_max: int, program: ModelProgram, one_cell: bool) -> None:
+    """Add this call's ticks, running ticks, shard slots, active shards and
+    skipped shards (`obs`) from the fetched trajectories: iterations ``j0``
+    to ``res.iterations`` of each replica ran in it. A shard slot is
+    skipped when its forward and backward did not run: on a vmapped grid
+    none is; one cell (`_one_cell`) skips every slot of its idle and
+    finished ticks, and with a ``cell_step_fn`` every inactive worker's."""
     j1 = res.iterations
     ticks = j1.size * n_run
+    running = int(np.sum(j1 - j0))
     at = np.arange(res.ys.shape[-1])
     ran = (at >= j0[..., None]) & (at < j1[..., None])
+    active = int(round(float(np.sum(np.where(ran, res.ys, 0.0)))))
+    computed = ticks * n_max
+    if one_cell:
+        computed = running * n_max if program.cell_step_fn is None else active
     obs.count("engine.ticks", ticks)
-    obs.count("engine.running_ticks", int(np.sum(j1 - j0)))
+    obs.count("engine.running_ticks", running)
     obs.count("engine.shard_slots", ticks * n_max)
-    obs.count("engine.active_shards",
-              int(round(float(np.sum(np.where(ran, res.ys, 0.0))))))
+    obs.count("engine.active_shards", active)
+    obs.count("engine.skipped_shards", ticks * n_max - computed)
 
 
 # --------------------------------------------------------------------------
@@ -1237,11 +1287,13 @@ def simulate_sharded(scenarios, program: ModelProgram, model0, data, seeds,
     Bit-exactness contract: per-cell RNG folds the seed *value* and the
     absolute tick index — never a device or shard position — so a sharded
     run is bit-identical to the single-device vmapped path, snapshots
-    included. Non-divisible grids are handled by padding each sharded axis
-    (repeating the last row) to a multiple of the axis size — with at
-    least 2 rows per shard on the CPU (see `_padded_size` for why) — and
-    slicing the padding back off the results. A fresh grid is built shard
-    by shard on its own devices (`initial_state`'s ``sharding``).
+    included. A device that holds one cell runs it as `simulate_program`
+    runs a one-cell grid (`_one_cell`), so its cell is bit-identical to
+    that cell run alone. Non-divisible grids are handled by padding each
+    sharded axis (repeating the last row) to a multiple of the axis size —
+    with at least 2 rows per shard on the CPU (see `_padded_size` for why)
+    — and slicing the padding back off the results. A fresh grid is built
+    shard by shard on its own devices (`initial_state`'s ``sharding``).
 
     ``mesh``: a `jax.sharding.Mesh` whose sharded axes are named ``data``
     (scenarios) and/or ``replica`` (seeds) — `repro.launch.mesh` has
@@ -1274,8 +1326,10 @@ def simulate_sharded(scenarios, program: ModelProgram, model0, data, seeds,
     n_run = _check_run_window(cfg, tick0)
     S, R = scenarios.n_scenarios, len(seeds)
     platform = mesh.devices.flat[0].platform
-    s_pad = _padded_size(S, _mesh_axis_size(mesh, "data"), platform)
-    r_pad = _padded_size(R, _mesh_axis_size(mesh, "replica"), platform)
+    s_shards = _mesh_axis_size(mesh, "data")
+    r_shards = _mesh_axis_size(mesh, "replica")
+    s_pad = _padded_size(S, s_shards, platform)
+    r_pad = _padded_size(R, r_shards, platform)
     batch_p = (scenarios if s_pad == S else
                jax.tree.map(lambda x: _pad_axis(x, 0, s_pad), scenarios))
     seeds_p = _pad_axis(seeds, 0, r_pad)
@@ -1300,7 +1354,9 @@ def simulate_sharded(scenarios, program: ModelProgram, model0, data, seeds,
         final = jax.tree.map(lambda x: x[:S, :R], final)
         if snaps is not None:
             snaps = jax.tree.map(lambda x: x[:S, :R], snaps)
-    return _engine_result(final, snaps, scenarios, cfg, tick0, n_run, j0)
+    return _engine_result(final, snaps, scenarios, cfg, tick0, n_run, j0,
+                          program, _one_cell(program, (s_pad // s_shards,
+                                                       r_pad // r_shards)))
 
 
 def snapshot_state(result: EngineResult, index: int = -1):

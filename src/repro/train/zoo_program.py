@@ -23,7 +23,10 @@ worker masking, with:
   per-worker microbatch shard weights inside `make_loss_grad`, renormalized
   with `core.elastic.weighted_mean`'s exact-zero convention — preempted
   workers' shards contribute nothing, all-preempted ticks are gated to
-  true no-ops by the engine.
+  true no-ops by the engine. A grid of one cell, which the engine runs
+  unvmapped, takes the program's ``cell_step_fn``: the same step fed the
+  batch as `train_step.WorkerSlices`, so only the active workers' slices
+  are computed.
 * **Pallas kernels**: ``cfg.use_flash_attention`` routes full-sequence
   self-attention through `kernels.ops.flash_mha` (and SSM configs already
   route SSD through the chunked kernel) — nothing extra to wire here; the
@@ -46,7 +49,8 @@ from repro.models import model_zoo
 from repro.models.common import abstract_params, init_params
 from repro.optim.sgd import constant_lr, get_optimizer
 from repro.sim import engine
-from repro.train.train_step import init_train_state, make_loss_grad
+from repro.train.train_step import init_train_state, make_loss_grad, \
+    worker_slices
 
 
 def is_mixed_precision(cfg: ModelConfig) -> bool:
@@ -131,12 +135,18 @@ def make_zoo_program(cfg: ModelConfig, job: JobConfig,
     static argument)."""
     step = make_zoo_step(cfg, job)
 
-    def step_fn(model, data, key, mask, j, alpha):
-        del key, alpha
-        batch = jax.tree.map(lambda x: x[j % n_batches], data)
-        new_model, loss = step(model, batch, mask, j)
-        return new_model, loss
+    def step_on(sliced):
+        def step_fn(model, data, key, mask, j, alpha):
+            del key, alpha
+            batch = jax.tree.map(lambda x: x[j % n_batches], data)
+            if sliced:
+                batch = worker_slices(batch, mask.shape[0])
+            new_model, loss = step(model, batch, mask, j)
+            return new_model, loss
+
+        return step_fn
 
     mode = "mixed" if is_mixed_precision(cfg) else "f32"
     return engine.ModelProgram(
-        step_fn=step_fn, name=f"zoo-{cfg.name}-{n_batches}-{mode}")
+        step_fn=step_on(False), cell_step_fn=step_on(True),
+        name=f"zoo-{cfg.name}-{n_batches}-{mode}")

@@ -96,6 +96,23 @@ def test_every_scope_names_ops_of_the_chunk_program(dtype):
     assert head_backward, "no backward matmul of the head in its scope"
 
 
+def test_a_grid_program_gates_under_its_scope():
+    """A vmapped grid lands the stepped model under ``repro.gate``: the
+    tick's ``cond`` on a batched predicate is a select over the carry."""
+    job = _job("float32")
+    batch = engine.stack_scenarios([_scenario()])
+    model0 = zoo_program.init_zoo_state(job.model, job,
+                                        jax.random.PRNGKey(0))
+    program = zoo_program.make_zoo_program(job.model, job, J)
+    hlo = engine._simulate_jit.lower(
+        batch, engine.initial_state(batch, model0, 2),
+        trainer.stack_batches(job, J), jnp.zeros((2,), jnp.int32),
+        jnp.int32(0), program=program, n_run=CHUNK,
+        k_snap=0).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    assert "repro.gate" in {_innermost_scope(name) for name in names}
+
+
 @pytest.mark.parametrize("sharded", [False, True])
 def test_counters_follow_the_mask(sharded):
     """On the trace's known mask, over two calls (the second resumes the
@@ -124,6 +141,21 @@ def test_counters_follow_the_mask(sharded):
         int(np.nansum(res.ys))
     assert sum(d["engine.running_ticks"] for d in deltas) == \
         int(res.iterations.sum())
+
+
+@pytest.mark.parametrize("seeds", [(0,), (0, 1)], ids=["one-cell", "grid"])
+def test_skipped_shards_follow_the_path(seeds):
+    """One cell branches on the mask and skips every slot it does not
+    train; a vmapped grid computes every slot."""
+    before = obs.snapshot()
+    _two_chunks(_job(), seeds)
+    after = obs.snapshot()
+    delta = {k: after.get(k, 0) - before.get(k, 0)
+             for k in COUNTERS + ("engine.skipped_shards",)}
+    skipped = delta["engine.shard_slots"] - delta["engine.active_shards"]
+    assert skipped > 0
+    assert delta["engine.skipped_shards"] == (skipped if len(seeds) == 1
+                                              else 0)
 
 
 def _host_spans(log_dir):

@@ -164,6 +164,46 @@ print("RESULT " + json.dumps(out))
 """
 
 
+# one cell per device: the CPU's padding floor (`engine._padded_size`)
+# would give each shard two rows, so the jitted sharded call is made
+# directly, as a chip with one cell each makes it
+_ONE_CELL_SCRIPT = _PRELUDE + r"""
+import jax.numpy as jnp
+from repro.configs import ARCHS
+from repro.configs.base import InputShape, JobConfig
+from repro.train import trainer, zoo_program
+
+cfg = ARCHS["qwen2-7b"].reduced().with_(
+    d_model=16, num_heads=2, num_kv_heads=1, d_ff=32, vocab_size=64,
+    head_dim=8)
+job = JobConfig(model=cfg, shape=InputShape("t", 8, 4, "train"),
+                n_workers=4, learning_rate=0.1)
+scen = [engine.Scenario(
+    price=engine.PriceSpec.uniform(0.2, 1.0), alpha=0.1,
+    bid_schedule=np.tile([b, b, 0.5, 0.5], (8, 1)), rt_kind="exp",
+    rt_lam=2.0, rt_delta=0.05, idle_step=0.5, name=f"g{i}")
+    for i, b in enumerate([0.9, 0.7])]
+batch, program, data, _ = trainer._prepare_batched(
+    job, scen, n_ticks=14, n_batches=None, batch_fn=None, batch_seed=0,
+    program=lambda n: zoo_program.make_zoo_program(cfg, job, n))
+model0 = zoo_program.init_zoo_state(cfg, job, jax.random.PRNGKey(0))
+mesh = make_scenario_mesh(2)
+args = (batch, engine.initial_state(batch, model0, 1), data,
+        jnp.zeros((1,), jnp.int32), jnp.int32(0))
+kw = dict(mesh=mesh, program=program, n_run=14, k_snap=0)
+text = engine._simulate_sharded_jit.lower(*args, **kw).as_text()
+final, _ = engine._simulate_sharded_jit(*args, **kw)
+out = {"branches": "stablehlo.case" in text or "stablehlo.if" in text}
+for i in range(2):
+    alone = engine.simulate_program(
+        [scen[i]], program, model0, data, [0],
+        engine.SimConfig(n_ticks=14))
+    row = jax.tree.map(lambda x: x[i:i + 1], final)
+    out[f"cell{i}"] = tree_equal(row, alone.final_state)
+print("RESULT " + json.dumps(out))
+"""
+
+
 def _run_subprocess(script):
     env = dict(os.environ, PYTHONPATH=SRC)
     env.pop("XLA_FLAGS", None)
@@ -195,6 +235,15 @@ def test_train_batched_sharded_bitexact():
     rec = _run_subprocess(_TRAINER_SCRIPT)
     bad = {k: v for k, v in rec.items() if not all(v.values())}
     assert not bad, f"sharded training diverged from vmapped: {bad}"
+
+
+@pytest.mark.slow
+def test_one_cell_per_device_branches_and_matches_each_cell_alone():
+    """A sharded grid with one cell per device takes the unvmapped,
+    branching tick inside ``shard_map``, and each device's cell lands
+    bit-exactly where that cell run alone lands."""
+    rec = _run_subprocess(_ONE_CELL_SCRIPT)
+    assert all(rec.values()), rec
 
 
 @pytest.mark.multidevice

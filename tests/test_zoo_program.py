@@ -16,6 +16,9 @@
    bit-for-bit where the uninterrupted run lands — the uint16-view bf16
    leaf round-trip in train/checkpoint.py included.
 """
+import dataclasses
+import functools
+
 import numpy as np
 import pytest
 
@@ -31,7 +34,7 @@ from repro.sim import engine
 from repro.sim.market_core import spot_active_mask
 from repro.train.loss import elastic_token_weights
 from repro.train.train_step import init_train_state, make_loss_grad, \
-    make_train_step
+    make_train_step, worker_slices
 from repro.train.trainer import resume_zoo, stack_batches, train_zoo
 from repro.train.zoo_program import init_zoo_state, is_mixed_precision, \
     make_zoo_step
@@ -93,8 +96,9 @@ def test_trace_schedule_mixes_full_partial_idle():
 def test_zoo_engine_matches_plain_loop_f32():
     """f32 zoo carry through the engine scan == a hand-rolled
     make_train_step loop under the same mask schedule, pinned at
-    float32-ulp tolerance (the engine's vmap batching refuses the exact
-    fusion order of the host loop, so last-ulp drift is the floor)."""
+    float32-ulp tolerance (the engine's scan refuses the exact fusion
+    order of the host loop, so last-ulp drift is the floor). A one-cell run
+    steps on per-worker slices, so the loop feeds the same slices."""
     cfg = _tiny_cfg()
     job = _job(cfg)
     res = train_zoo(job, [_trace_scenario()], seeds=[0], n_ticks=N_TICKS,
@@ -108,7 +112,8 @@ def test_zoo_engine_matches_plain_loop_f32():
     for running, mask in _hand_masks():
         if not running:
             continue
-        batch = jax.tree.map(lambda x: np.asarray(x)[j % J], data)
+        batch = worker_slices(
+            jax.tree.map(lambda x: np.asarray(x)[j % J], data), N_W)
         params, opt_state, metrics = step(params, opt_state, batch,
                                           jnp.asarray(mask),
                                           jnp.asarray(j, jnp.int32))
@@ -128,8 +133,9 @@ def test_zoo_engine_matches_plain_loop_f32():
 
 def test_zoo_engine_matches_plain_loop_bf16():
     """bf16 mixed-precision carry: the engine run is pinned (small atol —
-    the only difference is vmap/scan batching of bf16 ops) against an
-    independent host loop over the same `make_zoo_step` update rule."""
+    the only difference is scan batching of bf16 ops) against an
+    independent host loop over the same `make_zoo_step` update rule, fed
+    the per-worker slices a one-cell run steps on."""
     cfg = _tiny_cfg(dtype="bfloat16", param_dtype="bfloat16")
     assert is_mixed_precision(cfg)
     job = _job(cfg)
@@ -144,7 +150,8 @@ def test_zoo_engine_matches_plain_loop_bf16():
     for running, mask in _hand_masks():
         if not running:
             continue
-        batch = jax.tree.map(lambda x: np.asarray(x)[j % J], data)
+        batch = worker_slices(
+            jax.tree.map(lambda x: np.asarray(x)[j % J], data), N_W)
         model, loss = step(model, batch, jnp.asarray(mask),
                            jnp.asarray(j, jnp.int32))
         losses.append(float(loss))
@@ -303,3 +310,232 @@ def test_train_zoo_requires_cadence_with_checkpoint():
     with pytest.raises(ValueError, match="save_every"):
         train_zoo(_job(cfg), _uniform_grid(), seeds=[0],
                   checkpoint_path="/tmp/nope.npz")
+
+
+# ---------------------------------------------------------------------------
+# per-worker slices: only the active workers' shards are computed
+# ---------------------------------------------------------------------------
+
+MASKS = {"all": (1, 1, 1, 1), "half": (1, 1, 0, 0), "one": (0, 0, 1, 0),
+         "none": (0, 0, 0, 0)}
+#: (rtol, atol): the file's f32 pin, and its bf16 atol with an rtol of two
+#: bf16 roundings (machine epsilon 2^-7) for quantities rounded once
+TOL = {"float32": (1e-6, 1e-6), "bfloat16": (2 ** -6, 1e-5)}
+
+
+def _assert_leaf_close(a, b, rtol, atol):
+    """|a − b| ≤ rtol · max|b| + atol over one leaf. The scale is the
+    leaf's largest entry, not each entry: slices of opposite sign cancel,
+    and an entry near zero keeps the rounding of the larger terms."""
+    a = np.asarray(a, np.float32)
+    b = np.asarray(b, np.float32)
+    assert np.max(np.abs(a - b)) <= rtol * np.max(np.abs(b)) + atol, (
+        np.max(np.abs(a - b)), np.max(np.abs(b)))
+
+
+def _worst_error(tree, ref):
+    """The largest over leaves of max|a − r| / max|r|."""
+    return max(float(np.max(np.abs(np.asarray(a, np.float32) - r)) /
+                     max(float(np.max(np.abs(r))), 1e-30))
+               for a, r in zip(jax.tree.leaves(tree),
+                               jax.tree.leaves(ref)))
+
+
+@functools.lru_cache(maxsize=None)
+def _slice_programs(dtype):
+    """(cfg, job, grad step, zoo step), jitted once per dtype: the mask is
+    data, and the batch's layout picks the path."""
+    cfg = _tiny_cfg(dtype=dtype, param_dtype=dtype)
+    job = _job(cfg)
+    return (cfg, job, jax.jit(make_loss_grad(cfg, job, remat="none")),
+            jax.jit(make_zoo_step(cfg, job)))
+
+
+@pytest.mark.parametrize("mask", list(MASKS), ids=list(MASKS))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_worker_slices_match_whole_batch(dtype, mask):
+    """Gradient, loss and one zoo update from the per-worker conditional
+    slices against the whole batch's, for every active-worker pattern: in
+    float32 equal to the file's ulp pin. In bf16 each path's backward
+    rounds its own partial sums, so both are held to the float32 gradient
+    at the same bf16 parameters, and the slices (rounded per slice, summed
+    in float32) must be as near it as the whole batch, within a quarter."""
+    cfg, job, grad_step, zoo_step = _slice_programs(dtype)
+    rtol, atol = TOL[dtype]
+    model = init_zoo_state(cfg, job, jax.random.PRNGKey(1))
+    params = model[0] if dtype == "float32" else model["params"]
+    batch = _one_batch(job)
+    slices = worker_slices(batch, N_W)
+    m = jnp.asarray(MASKS[mask], jnp.float32)
+
+    grads, loss, _ = grad_step(params, slices, m)
+    ref_grads, ref_loss, _ = grad_step(params, batch, m)
+    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=rtol,
+                               atol=atol)
+    assert all(g.dtype == jnp.float32 for g in jax.tree.leaves(grads))
+    if dtype == "bfloat16":
+        _, _, f32_step, _ = _slice_programs("float32")
+        want, _, _ = f32_step(
+            jax.tree.map(lambda p: p.astype(jnp.float32), params), batch, m)
+        assert (_worst_error(grads, want) <=
+                1.25 * _worst_error(ref_grads, want) + atol)
+        return
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(ref_grads)):
+        _assert_leaf_close(a, b, rtol, atol)
+
+    # the update the engine lands, against the whole batch's: the
+    # parameters' change, so the tolerance applies to lr · g
+    j0 = jnp.asarray(0, jnp.int32)
+    (got, _), (want, _) = [zoo_step(model, b, m, j0)
+                           for b in (slices, batch)]
+    for a, b, o in zip(jax.tree.leaves(got[0]), jax.tree.leaves(want[0]),
+                       jax.tree.leaves(model[0])):
+        _assert_leaf_close(a - o, b - o, rtol, atol)
+
+
+@pytest.mark.parametrize("layout", ["whole", "slices"])
+def test_only_worker_slices_branch(layout):
+    """A dict batch is one forward and backward with no conditional (the
+    dry run and vmapped grids lower it, where a per-worker loop would run
+    the workers one after another); `WorkerSlices` branch per worker."""
+    cfg, job, _, _ = _slice_programs("float32")
+    params, _ = init_train_state(cfg, job, jax.random.PRNGKey(1))
+    batch = _one_batch(job)
+    if layout == "slices":
+        batch = worker_slices(batch, N_W)
+    text = jax.jit(make_loss_grad(cfg, job, remat="none")).lower(
+        params, batch, jnp.ones((N_W,), jnp.float32)).as_text()
+    assert ("stablehlo.case" in text) == (layout == "slices")
+
+
+def _tiny_moe_cfg():
+    return ARCHS["qwen2-moe-a2.7b"].reduced().with_(
+        d_model=64, num_heads=2, num_kv_heads=1, d_ff=64, vocab_size=256,
+        head_dim=32, moe=dataclasses.replace(
+            ARCHS["qwen2-moe-a2.7b"].moe, num_experts=4,
+            num_experts_unpadded=4, top_k=2, d_ff_expert=32,
+            num_shared_experts=1, d_ff_shared=32))
+
+
+@pytest.mark.parametrize("mask", ["all", "half", "one"])
+def test_moe_slices_route_alone(mask):
+    """An MoE model's slices each route alone: the sliced loss and
+    gradient are the Σw-weighted mean of each active slice's own
+    whole-batch step, and ``aux`` the mean of those slices' aux terms."""
+    cfg = _tiny_moe_cfg()
+    job = _job(cfg, b=8)
+    params, _ = init_train_state(cfg, job, jax.random.PRNGKey(1))
+    batch = _one_batch(job)
+    m = np.asarray(MASKS[mask], np.float32)
+    grad_step = jax.jit(make_loss_grad(cfg, job, remat="none"))
+    grads, loss, aux = grad_step(params, worker_slices(batch, N_W),
+                                 jnp.asarray(m))
+
+    per = 8 // N_W
+    terms = []
+    for i in np.flatnonzero(m):
+        one = jax.tree.map(lambda x: x[i * per:(i + 1) * per], batch)
+        g_i, l_i, a_i = grad_step(params, one, jnp.asarray(m[i:i + 1]))
+        w_i = float(elastic_token_weights(jnp.asarray(m[i:i + 1]), per,
+                                          one["labels"].shape[1]).sum())
+        terms.append((w_i, g_i, float(l_i), float(a_i)))
+    w_sum = sum(t[0] for t in terms)
+    np.testing.assert_allclose(
+        float(loss), sum(w * l for w, _, l, _ in terms) / w_sum, rtol=1e-6)
+    np.testing.assert_allclose(float(aux),
+                               np.mean([a for *_, a in terms]), rtol=1e-6)
+    want = jax.tree.map(lambda *g: sum(w * x for (w, *_), x in
+                                       zip(terms, g)) / w_sum,
+                        *[t[1] for t in terms])
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
+        _assert_leaf_close(a, b, *TOL["float32"])
+
+
+@pytest.mark.parametrize("vmapped", [False, True], ids=["cond", "select"])
+def test_masked_out_slice_keeps_non_finite_input_out(vmapped):
+    """A NaN in a preempted worker's patches reaches neither the gradient
+    nor the loss of the sliced step, under a real ``cond`` and under
+    vmap's ``select``."""
+    cfg = ARCHS["internvl2-1b"].reduced().with_(
+        d_model=64, num_heads=2, num_kv_heads=1, d_ff=128, vocab_size=256,
+        head_dim=32, vision=dataclasses.replace(
+            ARCHS["internvl2-1b"].vision, num_patches=4))
+    job = _job(cfg)
+    params, _ = init_train_state(cfg, job, jax.random.PRNGKey(1))
+    batch = _one_batch(job)
+    m = jnp.asarray([1, 1, 0, 1], jnp.float32)
+    poisoned = dict(batch, patches=np.asarray(batch["patches"]).copy())
+    poisoned["patches"][2] = np.nan          # worker 2's only row
+    grad_step = make_loss_grad(cfg, job, remat="none")
+    if vmapped:
+        # a batched mask: each slice's ``cond`` becomes a select
+        two = jax.jit(jax.vmap(
+            lambda p, b, mm: grad_step(p, worker_slices(b, N_W), mm),
+            in_axes=(None, 0, 0)))
+        (g, loss, _), (g0, loss0, _) = [
+            jax.tree.map(lambda x: x[0], two(
+                params, jax.tree.map(lambda *x: np.stack(x), b, batch),
+                jnp.stack([m, m])))
+            for b in (poisoned, batch)]
+    else:
+        g, loss, _ = jax.jit(grad_step)(params,
+                                        worker_slices(poisoned, N_W), m)
+        g0, loss0, _ = jax.jit(grad_step)(params,
+                                          worker_slices(batch, N_W), m)
+    assert np.isfinite(float(loss)) and float(loss) == float(loss0)
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(g0)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("seeds", [[0], [0, 1]], ids=["one-cell", "grid"])
+def test_idle_ticks_leave_the_carry_bit_equal(seeds):
+    """Prices above every bid: no tick runs, and the carry comes back
+    bit-equal, whether the tick branches (one cell) or selects (a grid)."""
+    cfg = _tiny_cfg(dtype="bfloat16", param_dtype="bfloat16")
+    job = _job(cfg)
+    sc = engine.Scenario(
+        price=engine.PriceSpec.from_trace_ticks(np.full(4, 0.95,
+                                                        np.float32)),
+        alpha=0.1, bid_schedule=np.tile(BIDS, (J, 1)), rt_kind="det",
+        rt_const=1.0, idle_step=0.5, name="idle")
+    res = train_zoo(job, [sc], seeds=seeds, n_ticks=4, donate=False)
+    assert int(res.iterations.sum()) == 0
+    model = init_zoo_state(cfg, job, jax.random.PRNGKey(job.seed))
+    for a, b in zip(jax.tree.leaves(res.final_model),
+                    jax.tree.leaves(model)):
+        for r in range(len(seeds)):
+            assert np.asarray(a)[0, r].tobytes() == np.asarray(b).tobytes()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_one_cell_run_matches_its_cell_in_a_grid(dtype):
+    """S = R = 1 runs unvmapped on per-worker slices; the same (scenario,
+    seed) inside a 2-seed grid is vmapped over the whole batch. On the
+    parity trace both realize the same masks and iterations exactly, and
+    the first loss agrees within the slices' tolerances; in float32 so
+    does the carry after the first tick. (Later steps start from carries
+    that differ by that rounding, so they are pinned against host loops
+    instead.)"""
+    cfg = _tiny_cfg(dtype=dtype, param_dtype=dtype)
+    job = _job(cfg)
+    rtol, atol = TOL[dtype]
+    one, grid = [train_zoo(job, [_trace_scenario()], seeds=seeds,
+                           n_ticks=N_TICKS, donate=False)
+                 for seeds in ([0], [0, 1])]
+    np.testing.assert_array_equal(one.ys[0, 0], grid.ys[0, 0])
+    np.testing.assert_array_equal(one.iterations[0, 0],
+                                  grid.iterations[0, 0])
+    np.testing.assert_allclose(one.losses[0, 0, 0], grid.losses[0, 0, 0],
+                               rtol=rtol, atol=atol)
+    if dtype == "bfloat16":
+        return          # the bf16 step's precision: the test above
+    one, grid = [train_zoo(job, [_trace_scenario()], seeds=seeds,
+                           n_ticks=1, donate=False)
+                 for seeds in ([0], [0, 1])]
+    assert int(one.iterations[0, 0]) == 1
+    model0 = init_zoo_state(cfg, job, jax.random.PRNGKey(job.seed))
+    for a, b, o in zip(jax.tree.leaves(one.final_model),
+                       jax.tree.leaves(grid.final_model),
+                       jax.tree.leaves(model0)):
+        _assert_leaf_close(np.asarray(a)[0, 0] - o,
+                           np.asarray(b)[0, 0] - o, rtol, atol)
