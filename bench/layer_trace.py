@@ -2,26 +2,35 @@
 
     python3 -m bench.layer_trace --workload <cell> --seed <n> --seconds <s>
 
-runs a cell's set-up and one traced window, as ``bench.run --trace 1``
-does, and prints one JSON line: the per-layer metrics of ``bench/layers/``
-that read the program's scopes, spans and counters (`repro.obs`), the
+runs a cell once with ``--trace 1``, through `bench.run`'s own traced
+path, and prints one JSON line: every per-layer reader of
+``bench/layers/`` (those ``BENCHMARK.json`` lists and the rest), the
 device seconds of each scope, the counters over the window, the window's
-tokens/s as traced, and the top ops and idle gaps named by their layer. It
-compares nothing with the reference. Needs a TPU, as a run does.
+tokens/s as traced, and the top ops and idle gaps named by their layer.
+Needs a TPU, as a run does.
 
-The reduction, on top of `bench.trace` (whose numbers it leaves as they
+The reduction, beside `bench.trace`'s (whose numbers it leaves as they
 are):
 
 - an op's layer: each device op's event metadata holds its ``op_name`` in
   the stat ``tf_op``, a path such as
   ``jit(f)/while/body/repro.step/transpose(jvp(repro.loss_head))/dot``.
-  The innermost of `SCOPES` in it is the op's scope; a backward or
-  recomputed op carries its forward scope in ``transpose(jvp(...))``, and
-  a fusion carries its root's. `bench.trace.load` keeps only names and
-  times, so the metadata is read from the file's protobuf here;
+  Its scope is the innermost name in that path, wrapped or not, that is a
+  scope: one of `SCOPES`, or one the program says it opened
+  (``repro.obs.scope_names()``, where the program has it; dotted names
+  such as ``repro.moe.route`` too). A backward or recomputed op carries
+  its forward scope in ``transpose(jvp(...))``, and a fusion carries its
+  root's. `bench.trace.load` keeps only names and times, so the metadata
+  is read from the file's protobuf here;
 - scope seconds: each op's self time in the window goes to its scope, or
   to ``unscoped``; they add up to the busy time, averaged over the chips
   that ran, as `bench.trace.reduce` averages it;
+- a hole: a loop op (``while``) does next to nothing itself, so where the
+  loops' self time on a chip passes `LOST_SHARE` of the window, the
+  profiler kept the loop but dropped ops nested in it, as it now and
+  then does. That time is ``lost_s``; it goes to no scope, and that
+  chip's scope seconds are scaled up to its busy time, so each share is
+  read over the ops the profile kept;
 - host idle: the window's idle time during which some host span of the
   program (``repro.*``) was open;
 - idle gaps are labelled with the innermost ``bench.*`` or ``repro.*``
@@ -35,36 +44,35 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import gc
 import json
 import os
 import re
-import shutil
 import sys
 import time
 from collections import defaultdict
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import (AbstractSet, Dict, FrozenSet, Iterator, List, Optional,
+                    Tuple)
 
 from bench import trace as trace_mod
 
 #: the stat of a device op's event metadata that holds its ``op_name``
 OP_NAME_STAT = "tf_op"
-#: the program's device scopes (`repro.obs`)
+#: the program's device scopes that every program since they came in opens
+#: (`repro.obs`); a program that declares its scopes adds its own
 SCOPES = ("repro.market", "repro.record", "repro.gate", "repro.step",
           "repro.loss_head", "repro.update")
-#: where an op under none of `SCOPES` is counted
+#: where an op under no scope is counted
 UNSCOPED = "unscoped"
 #: host spans of the program start with this
 PROGRAM_SPAN = "repro."
-#: the per-layer metrics this reduction feeds, as ``bench/layers/`` names
-#: them
-METRICS = ("loss_head_share", "update_share", "body_share", "market_share",
-           "host_idle", "useful_shard_share", "idle_tick_share")
-#: where the traced window's profile is written, read, then removed
-TRACE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                         ".work", "layer_trace")
+#: the kind of a loop op, whose own work is its condition: about no time
+LOOP = "while"
+#: loops' self time over this share of the window is nested ops the
+#: profiler dropped
+LOST_SHARE = 0.01
 
-_SCOPE_SEGMENT = re.compile(r"(?:^|[/(])(repro\.[a-z_]+)(?=[/):]|$)")
+#: the names in an ``op_name`` path: its segments, and what they wrap
+_NAME = re.compile(r"[^/(),:\s]+")
 
 
 # ------------------------------------------------- the profile's metadata
@@ -168,10 +176,24 @@ def program_spans(path: str) -> List[trace_mod.Event]:
             if e.name.startswith(PROGRAM_SPAN)]
 
 
-def scope_of(op_name: str) -> Optional[str]:
-    """The innermost of `SCOPES` among the path segments of ``op_name``
-    (a segment may be wrapped, as in ``transpose(jvp(repro.step))``)."""
-    found = [s for s in _SCOPE_SEGMENT.findall(op_name) if s in SCOPES]
+def known_scopes() -> FrozenSet[str]:
+    """`SCOPES` and every scope the program says it opened: the names
+    ``repro.obs.scope_names()`` returns, where the program has it. A
+    program records a name when it traces the scope, which a run does
+    even where its compiled programs come from the persistent cache."""
+    from repro import obs
+
+    declared = getattr(obs, "scope_names", None)
+    return frozenset(SCOPES).union(declared() if declared else ())
+
+
+def scope_of(op_name: str, scopes: Optional[AbstractSet[str]] = None
+             ) -> Optional[str]:
+    """The innermost name in ``op_name``'s path that is one of ``scopes``
+    (`known_scopes` by default); a name may be wrapped, as in
+    ``transpose(jvp(repro.step))``."""
+    scopes = known_scopes() if scopes is None else scopes
+    found = [n for n in _NAME.findall(op_name) if n in scopes]
     return found[-1] if found else None
 
 
@@ -189,6 +211,7 @@ class Layers:
     #                                       None if no op had a scope
     host_idle_s: Optional[float]          # idle under a program span; None
     #                                       if the program opened none
+    lost_s: float                         # nested ops the profiler dropped
     device_ops: List[Tuple[str, float]]   # top 10, ``<op> [<scope>]``
     unscoped_ops: List[Tuple[str, float]]  # top 10 of the ops in no scope
     idle_gaps: List[Tuple[str, float]]    # 10 longest, by innermost span
@@ -217,10 +240,11 @@ def reduce(trace: trace_mod.Trace, names: Dict[str, str],
            top: int = 10) -> Layers:
     """``trace`` with the program's spans among its ``spans``, and
     ``names`` from `op_names`."""
+    scopes = known_scopes()
     lo, hi = trace_mod.window_of(trace.spans)
     program = trace_mod.union([(a, b) for n, a, b in trace.spans
                                if n.startswith(PROGRAM_SPAN)], lo, hi)
-    busy_per_chip, host_idle = [], 0.0
+    busy_per_chip, host_idle, lost = [], 0.0, 0.0
     per_scope, per_op = defaultdict(float), defaultdict(float)
     unscoped = defaultdict(float)
     gaps: List[Tuple[str, float]] = []
@@ -228,12 +252,22 @@ def reduce(trace: trace_mod.Trace, names: Dict[str, str],
         busy = trace_mod.union([(a, b) for _, a, b in ops], lo, hi)
         if not busy:
             continue
-        busy_per_chip.append(sum(b - a for a, b in busy))
-        for name, t in trace_mod.self_times(ops, lo, hi).items():
-            scope = scope_of(names.get(name, ""))
-            per_scope[scope or UNSCOPED] += t
+        busy_s = sum(b - a for a, b in busy)
+        busy_per_chip.append(busy_s)
+        times = trace_mod.self_times(ops, lo, hi)
+        loops = {n for n in times
+                 if trace_mod.short_name(n).endswith(f" {LOOP}")}
+        loop_s = sum(times[n] for n in loops)
+        hole = LOST_SHARE * (hi - lo) < loop_s < busy_s
+        lost += loop_s if hole else 0.0
+        scale = busy_s / (busy_s - loop_s) if hole else 1.0
+        for name, t in times.items():
             short = trace_mod.short_name(name)
+            scope = scope_of(names.get(name, ""), scopes)
             per_op[f"{short} [{scope}]" if scope else short] += t
+            if hole and name in loops:
+                continue
+            per_scope[scope or UNSCOPED] += t * scale
             if scope is None:
                 unscoped[short] += t
         idle = trace_mod.gaps(busy, lo, hi)
@@ -255,15 +289,20 @@ def reduce(trace: trace_mod.Trace, names: Dict[str, str],
         scope_s=({s: t / chips * 1e-9 for s, t in per_scope.items()}
                  if scoped else None),
         host_idle_s=host_idle / chips * 1e-9 if program else None,
+        lost_s=lost / chips * 1e-9,
         device_ops=top_of(per_op), unscoped_ops=top_of(unscoped),
         idle_gaps=[list(g) for g in
                    sorted(gaps, key=lambda g: -g[1])[:top]])
 
 
-def load(path: str) -> Layers:
+def load(path: str) -> Tuple[trace_mod.Reduced, Layers]:
+    """The profile at ``path`` reduced twice: by `bench.trace.reduce`,
+    over the harness's spans alone (the device idle share and the traced
+    line's breakdown), and by layer."""
     trace = trace_mod.load(path)
-    trace.spans += program_spans(path)
-    return reduce(trace, op_names(path))
+    plain = trace_mod.reduce(trace)
+    trace.spans = trace.spans + program_spans(path)
+    return plain, reduce(trace, op_names(path))
 
 
 # ---------------------------------------------- what the readers share
@@ -272,12 +311,12 @@ def load(path: str) -> Layers:
 def scope_share(record, *scopes: str) -> Optional[float]:
     """Percent of the traced window the chip spent in ``scopes``: None
     without a trace, or against a program without scopes."""
-    trace = record.get("trace")
-    per_scope = getattr(trace, "scope_s", None)
+    layers = record.get("layers")
+    per_scope = getattr(layers, "scope_s", None)
     if not per_scope:
         return None
     return 100.0 * sum(per_scope.get(s, 0.0) for s in scopes) / \
-        trace.window_s
+        layers.window_s
 
 
 def counter_ratio(record, part: str, whole: str) -> Optional[float]:
@@ -292,50 +331,39 @@ def counter_ratio(record, part: str, whole: str) -> Optional[float]:
 # ----------------------------------------------------------- a traced run
 
 
-def measure(cell, seed: int, seconds: float, compile_count,
-            trace_dir: str = TRACE_DIR) -> dict:
-    """Set-up and one traced window of ``cell``: the result line."""
-    import jax
+def readers() -> List[str]:
+    """Every per-layer metric that has a reader under ``bench/layers/``."""
+    here = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "layers")
+    return sorted(f[:-3] for f in os.listdir(here)
+                  if f.endswith(".py") and not f.startswith("_"))
 
+
+def measure(cell, seed: int, seconds: float, clock, peaks, device,
+            t_start: float) -> dict:
+    """One run of ``cell`` through `bench.run`'s traced path, set-up,
+    window, trace and reference alike: the line this module prints."""
     from bench import run as bench_run
-    from bench import train_cell
-    from repro import obs
 
-    plan = train_cell.make_plan(cell.config, cell.traffic, seed)
-    run = train_cell.Run()
-    state, tick, scenarios = train_cell.setup(plan, run)
-    jax.block_until_ready(state)
-    gc.collect()
-    gc.freeze()
-    shutil.rmtree(trace_dir, ignore_errors=True)
-    before = obs.snapshot()
-    jax.profiler.start_trace(trace_dir)
-    try:
-        state = train_cell.window(plan, run, state, tick, scenarios,
-                                  seconds, compile_count)
-    finally:
-        jax.profiler.stop_trace()
-    after = obs.snapshot()
-    del state
-    layers = load(trace_mod.newest_xplane(trace_dir))
-    shutil.rmtree(trace_dir, ignore_errors=True)
-    record = {"window_s": run.window_s, "trace": layers,
-              "counters": {k: v - before.get(k, 0)
-                           for k, v in after.items()}}
-    metrics = {m: bench_run.layer_value(m, record) for m in METRICS}
+    line, record, run = bench_run.measure(cell, seed, seconds, True, clock,
+                                          peaks, device, t_start)
+    layers = record["layers"]
     scope_s = layers.scope_s or {}
-    return {"cell": cell.name, "seed": seed, "metrics": metrics,
-            "device_idle": 100.0 * layers.idle_share,
+    return {"cell": cell.name, "seed": seed, "correct": line["correct"],
+            "metrics": {m: bench_run.layer_value(m, record)
+                        for m in readers()},
+            "device_idle": 100.0 * record["trace"].idle_share,
             "tokens_per_s_traced": run.tokens / run.window_s,
             "window_s": layers.window_s, "busy_s": layers.busy_s,
             "scope_s": {s: t for s, t in scope_s.items() if s != UNSCOPED},
             "unscoped_s": scope_s.get(UNSCOPED),
-            "host_idle_s": layers.host_idle_s,
+            "host_idle_s": layers.host_idle_s, "lost_s": layers.lost_s,
             "counters": record["counters"], "chunk_s": run.chunk_s,
             "compiles_in_window": run.compiles_in_window,
             "breakdown": {"device_ops": layers.device_ops,
                           "unscoped_ops": layers.unscoped_ops,
-                          "idle_gaps": layers.idle_gaps}}
+                          "idle_gaps": layers.idle_gaps},
+            "device": line["device"]}
 
 
 def main(argv=None) -> int:
@@ -347,26 +375,12 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     from bench import run as bench_run
 
-    sys.path.insert(0, bench_run.SRC)
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = bench_run.CACHE_DIR
-    import jax
-
-    from bench.spans import CompileClock
-    from bench.spec import load_cell
-    from repro.launch.jitcache import enable_persistent_cache
-
-    clock = CompileClock()
-    enable_persistent_cache()
-    bench_run.configure_cache(jax)
-    device = jax.devices()[0]
-    if device.platform != "tpu":
-        print("bench.layer_trace: JAX found no TPU", file=sys.stderr)
-        return 2
-    line = measure(load_cell(args.workload), args.seed, args.seconds,
-                   lambda: clock.compiles)
-    line["device"] = {"platform": device.platform,
-                      "kind": device.device_kind,
-                      "count": len(jax.devices())}
+    opened = bench_run.open_cell(args.workload, args.seed)
+    if isinstance(opened, int):
+        return opened
+    cell, clock, peaks, device = opened
+    line = measure(cell, args.seed, args.seconds, clock, peaks, device,
+                   t_start)
     line["wall_s"] = time.perf_counter() - t_start
     print(json.dumps(line), flush=True)
     return 0

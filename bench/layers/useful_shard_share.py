@@ -1,7 +1,9 @@
-"""Share of the worker shards the model step computed over the window
-that trained: the engine's ``active_shards`` (Σ active workers over the
-iterations that ran) over its ``shard_slots`` (ticks × workers). The step
-computes every worker's shard on every tick, so the rest is discarded."""
+"""Share of the window's worker-shard slots (ticks × workers) that
+trained: the engine's ``active_shards`` (Σ active workers over the
+iterations that ran) over its ``shard_slots``. It counts slots, not work:
+the traffic fixes it (62.5 % under the spot mix), whether or not the step
+computes the other slots' shards. A `bench.layer_trace` printout, not a
+``BENCHMARK.json`` metric."""
 from bench.layer_trace import counter_ratio
 
 

@@ -77,18 +77,20 @@ def configure_cache(jax) -> None:
     jax.config.update("jax_compilation_cache_max_size", -1)
 
 
-def main(argv=None) -> int:
-    args = parse(argv)
+def open_cell(workload: str, seed: int):
+    """Everything a run does before its set-up: the cell's files, the
+    compile clock and cache, and the chips. Returns (cell, clock, peaks,
+    device), or the exit code of a refusal."""
     if not os.path.isdir(os.path.join(SRC, "repro")):
         return _refuse(f"no program under {SRC}: run from a checkout")
-    if args.seed < 0:
-        return _refuse(f"--seed {args.seed} is negative")
+    if seed < 0:
+        return _refuse(f"--seed {seed} is negative")
     sys.path.insert(0, SRC)
     os.environ["JAX_COMPILATION_CACHE_DIR"] = CACHE_DIR
 
     from bench.spec import load_cell
 
-    cell = load_cell(args.workload)
+    cell = load_cell(workload)
 
     import jax
 
@@ -105,27 +107,48 @@ def main(argv=None) -> int:
         return _refuse(f"JAX found no TPU (platform {platform!r}); the "
                        "benchmark measures the chip only")
     if len(devices) < cell.chips:
-        return _refuse(f"{args.workload} needs {cell.chips} chips, JAX "
+        return _refuse(f"{workload} needs {cell.chips} chips, JAX "
                        f"sees {len(devices)}")
     try:
         peaks = peaks_for(devices[0].device_kind)
     except KeyError as e:
         return _refuse(str(e))
+    return cell, clock, peaks, {"platform": platform,
+                                "kind": devices[0].device_kind,
+                                "count": len(devices)}
 
+
+def main(argv=None) -> int:
+    args = parse(argv)
+    opened = open_cell(args.workload, args.seed)
+    if isinstance(opened, int):
+        return opened
+    cell, clock, peaks, device = opened
     line = measure(cell, args.seed, args.seconds, bool(args.trace), clock,
-                   peaks, {"platform": platform,
-                           "kind": devices[0].device_kind,
-                           "count": len(devices)})
+                   peaks, device)[0]
     sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 
 
+def read_trace(record: dict, profile: str, counters) -> None:
+    """What a traced window gives the per-layer readers, added to
+    ``record``: ``trace``, the profile reduced by `bench.trace.reduce`
+    (the device idle share and the line's breakdown); ``layers``, the
+    profile by the program's scopes and spans (`bench.layer_trace`); and
+    ``counters``, the program's counters over the window."""
+    from bench import layer_trace
+
+    record["trace"], record["layers"] = layer_trace.load(profile)
+    record["counters"] = counters
+
+
 def measure(cell, seed: int, seconds: float, traced: bool, clock, peaks,
-            device, t_start: float = None) -> dict:
-    """One run of ``cell`` on whatever devices JAX has: the result line.
-    Prints the numbers compared, each beside its limit, as the last lines
-    of standard error."""
+            device, t_start: float = None):
+    """One run of ``cell`` on whatever devices JAX has: (the result line,
+    the record the per-layer readers read, the `train_cell.Run`). Prints
+    the numbers compared, each beside its limit, as the last lines of
+    standard error."""
     from bench import check
     from bench import trace as trace_mod
     from bench import train_cell
@@ -148,14 +171,22 @@ def measure(cell, seed: int, seconds: float, traced: bool, clock, peaks,
     record = {"window_s": run.window_s, "chips": cell.chips,
               "useful_flops": rows * flops_per_row(cell.config),
               "peak_flops": peaks["bf16_flops_per_s"],
-              "compile_s": compile_s_setup[0], "trace": None}
+              "compile_s": compile_s_setup[0], "trace": None,
+              "layers": None, "counters": None}
     line = {"correct": correct and run.nonfinite == 0,
             "attempted": run.iterations, "failed": run.nonfinite}
     if traced:
-        reduced = trace_mod.reduce(trace_mod.load(
-            trace_mod.newest_xplane(trace_dir)))
+        t0 = time.perf_counter()
+        read_trace(record, trace_mod.newest_xplane(trace_dir),
+                   run.counters)
+        read_s = time.perf_counter() - t0
         shutil.rmtree(trace_dir, ignore_errors=True)
-        record["trace"] = reduced
+        lost_s = record["layers"].lost_s
+        if lost_s:
+            print(f"bench.run: the profile lost {lost_s!r} s of ops nested "
+                  "in a loop; the scope shares are over the ops it kept",
+                  file=sys.stderr)
+        reduced = record["trace"]
         device.update(busy_s=reduced.busy_s, window_s=reduced.window_s)
         metrics = {}
         for m in cell.per_layer:
@@ -181,11 +212,13 @@ def measure(cell, seed: int, seconds: float, traced: bool, clock, peaks,
               "chunk_s": run.chunk_s, "gc_s": run.gc_s,
               "host_cpu_s": run.host_cpu_s,
               "reference_s": run.reference_s, "seed": seed}
+    if traced:
+        detail.update(read_trace_s=read_s, lost_s=lost_s)
     print(f"bench.run: {json.dumps(detail)}", file=sys.stderr)
     for name, row in table.items():
         print(f"check {name} = {row['value']!r} (limit {row['limit']!r})",
               file=sys.stderr)
-    return line
+    return line, record, run
 
 
 if __name__ == "__main__":

@@ -8,10 +8,10 @@ import io
 import pytest
 
 from bench import calibrate, check
-from bench.tests.tiny import tiny_cell
+from bench.tests.tiny import CELLS, tiny_cell
 
 
-@pytest.mark.parametrize("name", ["internvl2-1b.spot", "mamba2-1.3b.spot"])
+@pytest.mark.parametrize("name", CELLS)
 def test_control_fails_where_the_program_passes(name):
     cell = tiny_cell(name)
     recs = {r["variant"]: r for r in calibrate.readings(

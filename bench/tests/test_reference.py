@@ -11,10 +11,10 @@ import jax.numpy as jnp
 
 from bench import traffic, train_cell
 from bench.reference import common, mamba2
-from bench.tests.tiny import tiny_cell
+from bench.tests.tiny import CELLS, tiny_cell
 
 
-@pytest.mark.parametrize("name", ["internvl2-1b.spot", "mamba2-1.3b.spot"])
+@pytest.mark.parametrize("name", CELLS)
 def test_reference_step_matches_zoo_step(name):
     from repro.train.zoo_program import make_zoo_step
 

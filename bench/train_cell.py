@@ -17,6 +17,7 @@ import functools
 import gc
 import json
 import time
+import typing
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -40,18 +41,38 @@ def reference_module(config: Dict):
     return load_module("reference", config["reference"])
 
 
+def config_groups() -> List[str]:
+    """The fields of the program's `ModelConfig` that hold a group of
+    their own, a dataclass such as ``ssm`` or ``moe``."""
+    from repro.configs.base import ModelConfig
+
+    hints = typing.get_type_hints(ModelConfig)
+    return [f.name for f in dataclasses.fields(ModelConfig)
+            if any(dataclasses.is_dataclass(k) for k in
+                   typing.get_args(hints[f.name]) or (hints[f.name],))]
+
+
 def model_config(config: Dict):
     """The program's `ModelConfig` for a configuration file: the arch of
-    ``configs.ARCHS`` with every field of the file's ``model`` set."""
+    ``configs.ARCHS`` with every field of the file's ``model`` set, and
+    every nested group merged into the arch's own group of that name."""
     from repro.configs import ARCHS
 
     base = ARCHS[config["arch"]]
-    kw = {k: v for k, v in config["model"].items()
-          if k not in ("ssm", "vision")}
-    for group in ("ssm", "vision"):
-        if group in config["model"]:
-            kw[group] = dataclasses.replace(getattr(base, group),
-                                            **config["model"][group])
+    groups = config_groups()
+    kw = {}
+    for key, value in config["model"].items():
+        if not isinstance(value, dict):
+            kw[key] = value
+        elif key not in groups:
+            raise ValueError(f"model.{key} is a group, but ModelConfig's "
+                             f"{key!r} is not one (groups: "
+                             f"{sorted(groups)})")
+        elif getattr(base, key) is None:
+            raise ValueError(f"model.{key}: the architecture "
+                             f"{config['arch']!r} has no {key!r} group")
+        else:
+            kw[key] = dataclasses.replace(getattr(base, key), **value)
     return base.with_(**kw)
 
 
@@ -215,6 +236,8 @@ class Run:
     host_cpu_s: float = 0.0       # this process's CPU time in the window
     nonfinite: int = 0            # window iterations with a non-finite loss
     reference_s: float = 0.0
+    counters: Optional[Dict[str, int]] = None   # the program's, over a
+    #                                             traced window
 
 
 def _train(plan: Plan, scenarios, state, tick: int):
@@ -349,8 +372,9 @@ def reference_readings(plan: Plan, variant: str = "f32") -> ref.Readings:
 def run_cell(plan: Plan, seconds: float, compile_count: Callable[[], int],
              t_start: float, trace_dir: Optional[str] = None,
              on_setup_done: Optional[Callable[[], None]] = None):
-    """A whole run: set-up, window (traced into ``trace_dir`` when given),
-    peak memory, then the reference once the program's state is gone.
+    """A whole run: set-up, window (traced into ``trace_dir`` when given,
+    with the program's counters taken around it), peak memory, then the
+    reference once the program's state is gone.
     Returns (Run, the reference's readings)."""
     run = Run()
     state, tick, scenarios = setup(plan, run)
@@ -363,6 +387,9 @@ def run_cell(plan: Plan, seconds: float, compile_count: Callable[[], int],
     if on_setup_done is not None:
         on_setup_done()
     if trace_dir is not None:
+        from repro import obs
+
+        before = obs.snapshot()
         jax.profiler.start_trace(trace_dir)
     try:
         state = window(plan, run, state, tick, scenarios, seconds,
@@ -370,6 +397,9 @@ def run_cell(plan: Plan, seconds: float, compile_count: Callable[[], int],
     finally:
         if trace_dir is not None:
             jax.profiler.stop_trace()
+    if trace_dir is not None:
+        run.counters = {k: v - before.get(k, 0)
+                        for k, v in obs.snapshot().items()}
     run.memory_peak_bytes = peak_bytes()
     del state
     gc.unfreeze()
